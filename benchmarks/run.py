@@ -47,6 +47,8 @@ MODULES = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     selected = set(sys.argv[1:])
     print("name,us_per_call,derived")
     failures = []

@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 
 from repro.content.chunks import BYTES_PER_TOKEN, chunk_sizes
 from repro.kernels.backend import resolve_interpret
+from repro.kernels.mesi_transition import pick_lane, pick_row, slot_add
 
 N_CHUNK_COUNTERS = 4
 
@@ -68,64 +69,64 @@ def _chunk_kernel(cv_ref, cs_ref, dirty_ref, miss_ref, wact_ref, art_ref,
                   *, n_agents: int, n_artifacts: int, n_chunks: int,
                   chunk_tokens: int, artifact_tokens: int,
                   signal_tokens: int):
-    cv = cv_ref[...]        # (bs, m, C) int32 authority chunk versions
-    cs = cs_ref[...]        # (bs, n, m, C) reader chunk vectors
-    dirty = dirty_ref[...]  # (bs, m, C) ever-written bitmap
-    miss = miss_ref[...]    # (bs, n) fill indicator from the MESI tick
-    wact = wact_ref[...]    # (bs, n) acting-write indicator
-    arts = art_ref[...]     # (bs, n) chosen artifact
-    wmask = wmask_ref[...]  # (bs, n, C) dirtied chunk span per writer
-    bs = cv.shape[0]
-    # (1, C) chunk token sizes from the static geometry (a ragged last
-    # chunk); built with iota - array constants can't be captured.
-    cidx = jax.lax.broadcasted_iota(jnp.int32, (1, n_chunks), 1)
+    # Values are rank 3 with the sim axis leading, as in the MESI
+    # kernel: chunk planes (bs, m, C), per-agent rows (bs, 1, n), per-sim
+    # scalars (bs, 1, 1).  The carried state lives in the output refs;
+    # agent ``a``'s reader vectors are the (bs, m, C) plane cs[:, a].
+    cv_out[...] = cv_ref[...]
+    cs_out[...] = cs_ref[...]
+    dirty_out[...] = dirty_ref[...]
+    fetched_out[...] = jnp.zeros(fetched_out.shape, jnp.int32)
+    counter_out[...] = jnp.zeros(counter_out.shape, jnp.int32)
+    bs = cv_ref.shape[0]
+    agent_col = jax.lax.broadcasted_iota(jnp.int32, (bs, n_agents, 1), 1)
+    agent_lane = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, n_agents), 2)
+    art_col = jax.lax.broadcasted_iota(jnp.int32, (bs, n_artifacts, 1), 1)
+    # (1, 1, C) chunk token sizes from the static geometry (a ragged
+    # last chunk); built with iota - array constants can't be captured.
+    cidx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_chunks), 2)
     last = artifact_tokens - (n_chunks - 1) * chunk_tokens
     sizes_row = jnp.where(cidx < n_chunks - 1, chunk_tokens, last)
-    counters = jnp.zeros((bs, N_CHUNK_COUNTERS), jnp.int32)
-    fetched = jnp.zeros((bs, n_agents, n_chunks), jnp.int32)
 
     def agent_body(a, carry):
-        cv, cs, dirty, fetched, counters = carry
-        miss_a = miss[:, a] != 0                    # (bs,)
-        w_a = wact[:, a] != 0
-        d_oh = (jax.lax.broadcasted_iota(jnp.int32, (bs, n_artifacts), 1)
-                == arts[:, a][:, None])             # (bs, m)
-        d3 = d_oh[:, :, None]                       # (bs, m, 1)
+        row = agent_col == a                        # (bs, n, 1)
+        a_oh = agent_lane == a                      # (bs, 1, n)
+        miss_a = pick_lane(a_oh, miss_ref[...]) != 0          # (bs, 1, 1)
+        w_a = pick_lane(a_oh, wact_ref[...]) != 0
+        d3 = art_col == pick_lane(a_oh, art_ref[...])         # (bs, m, 1)
+        cv = cv_out[...]
+        cs_a = cs_out[:, a]                                   # (bs, m, C)
 
         # --- delta fetch at this agent's serialization slot
-        ver_at = jnp.sum(jnp.where(d3, cv, 0), axis=1)        # (bs, C)
-        sync_at = jnp.sum(jnp.where(d3, cs[:, a, :, :], 0), axis=1)
-        fetch = jnp.logical_and(miss_a[:, None], ver_at > sync_at)
-        delta_tok = jnp.sum(jnp.where(fetch, sizes_row, 0), axis=1)
-        counters = counters.at[:, 0].add(jnp.where(
+        ver_at = jnp.sum(jnp.where(d3, cv, 0), axis=1, keepdims=True)
+        sync_at = jnp.sum(jnp.where(d3, cs_a, 0), axis=1, keepdims=True)
+        fetch = jnp.logical_and(miss_a, ver_at > sync_at)     # (bs, 1, C)
+        fetch_i = fetch.astype(jnp.int32)
+        delta_tok = jnp.sum(jnp.where(fetch, sizes_row, 0), axis=2,
+                            keepdims=True)
+        counters = counter_out[...]
+        counters = slot_add(counters, 0, jnp.where(
             miss_a, (delta_tok + signal_tokens) * BYTES_PER_TOKEN, 0))
-        counters = counters.at[:, 1].add(jnp.where(
+        counters = slot_add(counters, 1, jnp.where(
             miss_a, (artifact_tokens + signal_tokens) * BYTES_PER_TOKEN,
             0))
-        counters = counters.at[:, 2].add(
-            jnp.sum(fetch.astype(jnp.int32), axis=1))
-        fetched = fetched.at[:, a, :].set(fetch.astype(jnp.int32))
-        fill = jnp.logical_and(miss_a[:, None, None], d3)     # (bs, m, 1)
-        cs_a = jnp.where(fill, cv, cs[:, a, :, :])            # (bs, m, C)
+        counters = slot_add(counters, 2, jnp.sum(fetch_i, axis=2,
+                                                 keepdims=True))
+        counter_out[...] = counters
+        fetched_out[...] = jnp.where(row, fetch_i, fetched_out[...])
+        cs_a = jnp.where(jnp.logical_and(miss_a, d3), cv, cs_a)
 
         # --- chunk-granular commit: bump the dirtied span
+        wd = jnp.logical_and(w_a, d3)                         # (bs, m, 1)
         bump = jnp.logical_and(
-            jnp.logical_and(w_a[:, None, None], d3),
-            wmask[:, a, :][:, None, :] != 0)                  # (bs, m, C)
+            wd, pick_row(row, wmask_ref[...]) != 0)           # (bs, m, C)
         cv = jnp.where(bump, cv + 1, cv)
-        dirty = jnp.where(bump, 1, dirty)
-        cs_a = jnp.where(jnp.logical_and(w_a[:, None, None], d3),
-                         cv, cs_a)
-        cs = cs.at[:, a, :, :].set(cs_a)
-        return cv, cs, dirty, fetched, counters
+        dirty_out[...] = jnp.where(bump, 1, dirty_out[...])
+        cv_out[...] = cv
+        cs_out[:, a] = jnp.where(wd, cv, cs_a)
+        return carry
 
-    cv, cs, dirty, fetched, counters = jax.lax.fori_loop(
-        0, n_agents, agent_body, (cv, cs, dirty, fetched, counters))
-    cv_out[...] = cv
-    cs_out[...] = cs
-    dirty_out[...] = dirty
-    fetched_out[...] = fetched
-    counter_out[...] = counters
+    jax.lax.fori_loop(0, n_agents, agent_body, 0)
 
 
 def chunk_tick_pallas(chunk_version, chunk_sync, chunk_dirty,
@@ -145,46 +146,35 @@ def chunk_tick_pallas(chunk_version, chunk_sync, chunk_dirty,
     interpret = resolve_interpret(interpret)
     B, n, m, C = chunk_sync.shape
     bs = min(block_sims, B)
+    # per-agent rows get a unit axis: the kernel body is rank 3
+    args = [chunk_version, chunk_sync, chunk_dirty, miss[:, None, :],
+            write_acts[:, None, :], arts[:, None, :], write_chunks]
     pad = (-B) % bs
     if pad:
-        padded = []
-        for arr in (chunk_version, chunk_sync, chunk_dirty, miss,
-                    write_acts, arts, write_chunks):
-            padded.append(jnp.pad(arr, [(0, pad)] + [(0, 0)] *
-                                  (arr.ndim - 1)))
-        (chunk_version, chunk_sync, chunk_dirty, miss, write_acts, arts,
-         write_chunks) = padded
-    Bp = chunk_version.shape[0]
-    grid = (Bp // bs,)
+        args = [jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
+                for x in args]
+    Bp = B + pad
     kernel = functools.partial(
         _chunk_kernel, n_agents=n, n_artifacts=m, n_chunks=C,
         chunk_tokens=chunk_tokens, artifact_tokens=artifact_tokens,
         signal_tokens=signal_tokens)
-    spec_mc = pl.BlockSpec((bs, m, C), lambda i: (i, 0, 0))
-    spec_nmc = pl.BlockSpec((bs, n, m, C), lambda i: (i, 0, 0, 0))
-    spec_n = pl.BlockSpec((bs, n), lambda i: (i, 0))
-    spec_nc = pl.BlockSpec((bs, n, C), lambda i: (i, 0, 0))
+    blocks = [(bs, m, C), (bs, n, m, C), (bs, m, C), (bs, 1, n),
+              (bs, 1, n), (bs, 1, n), (bs, n, C)]
+    out_blocks = [(bs, m, C), (bs, n, m, C), (bs, m, C), (bs, n, C),
+                  (bs, 1, N_CHUNK_COUNTERS)]
+    spec = lambda blk: pl.BlockSpec(
+        blk, lambda i: (i,) + (0,) * (len(blk) - 1))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[spec_mc, spec_nmc, spec_mc, spec_n, spec_n, spec_n,
-                  spec_nc],
-        out_specs=[spec_mc, spec_nmc, spec_mc, spec_nc,
-                   pl.BlockSpec((bs, N_CHUNK_COUNTERS),
-                                lambda i: (i, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, m, C), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, n, m, C), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, m, C), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, n, C), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, N_CHUNK_COUNTERS), jnp.int32),
-        ],
+        grid=(Bp // bs,),
+        in_specs=[spec(b) for b in blocks],
+        out_specs=[spec(b) for b in out_blocks],
+        out_shape=[jax.ShapeDtypeStruct((Bp,) + b[1:], jnp.int32)
+                   for b in out_blocks],
         interpret=interpret,
-    )(chunk_version, chunk_sync, chunk_dirty, miss, write_acts, arts,
-      write_chunks)
-    if pad:
-        out = tuple(o[:B] for o in out)
-    return out
+    )(*args)
+    cv, cs, dirty, fetched, counters = (o[:B] for o in out)
+    return cv, cs, dirty, fetched, counters[:, 0]
 
 
 def chunk_tick_ref(chunk_version, chunk_sync, chunk_dirty,

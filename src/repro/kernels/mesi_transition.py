@@ -8,10 +8,11 @@ the (n_agents x n_artifacts) coherence matrix of every simulation.
 TPU adaptation: one program owns a ``block_sims`` slab of simulations
 resident in VMEM; agents are processed with a sequential fori_loop
 (the authority's serialization order - a *semantic* requirement, not a
-perf artifact) while the simulation dimension is fully vectorized on the
-8x128 VPU lanes.  Dynamic per-sim artifact indices become one-hot masks
-over the artifact dim (m <= 16), trading a few lanes of redundancy for
-fully static shapes - the standard TPU answer to data-dependent
+perf artifact) while the simulation dimension is vectorized.  Mosaic
+lowers neither a dynamic slice nor a scatter, so the loop's agent index
+and each sim's artifact index select and update by one-hot masks over
+the agent and artifact axes (m <= 16), and counters take masked adds -
+fully static shapes, the standard TPU answer to data-dependent
 indexing.
 
 Counters layout (out[..., c]): 0 fetch_tokens, 1 signal_tokens,
@@ -34,6 +35,10 @@ from repro.kernels.backend import resolve_interpret
 _I, _S = int(MESIState.I), int(MESIState.S)
 N_COUNTERS = 8
 
+#: sims per grid step of ``mesi_decision_batch``: its n+1 prefix sims
+#: tile over a grid so that a 256-agent directory block stays within VMEM.
+DECISION_BLOCK = 8
+
 
 def episode_step_keys(keys: jax.Array, n_steps: int) -> jax.Array:
     """Per-step PRNG keys for a batch of kernel-routed episodes.
@@ -52,100 +57,118 @@ def episode_step_keys(keys: jax.Array, n_steps: int) -> jax.Array:
     return jnp.swapaxes(step_keys, 0, 1)
 
 
+def pick_lane(lane_oh: jax.Array, x: jax.Array) -> jax.Array:
+    """Entry of ``x`` (bs, 1, k) under the one-hot lane mask ``lane_oh``
+    (bs, 1, k): ``(bs, 1, 1)``.  Mosaic lowers no dynamic slice, so the
+    loop's agent index and each sim's artifact index select by mask."""
+    return jnp.sum(jnp.where(lane_oh, x, 0), axis=2, keepdims=True)
+
+
+def pick_row(row: jax.Array, x: jax.Array) -> jax.Array:
+    """Agent row of a directory array: ``row`` is the (bs, n, 1) one-hot
+    mask of the agent axis, ``x`` (bs, n, m) -> (bs, 1, m)."""
+    return jnp.sum(jnp.where(row, x, 0), axis=1, keepdims=True)
+
+
+def slot_add(counters: jax.Array, slot: int, value: jax.Array
+              ) -> jax.Array:
+    """``counters[..., slot] += value`` as a masked add over a lane iota
+    (Mosaic has no scatter): ``counters`` (bs, 1, K), ``value``
+    (bs, 1, 1)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, counters.shape, 2)
+    return counters + jnp.where(lane == slot, value, 0)
+
+
 def _mesi_kernel(state_ref, version_ref, sync_ref, reads_ref,
                  act_ref, art_ref, write_ref,
                  state_out, version_out, sync_out, reads_out, counter_out,
                  miss_out,
                  *, n_agents: int, n_artifacts: int, artifact_tokens: int,
                  eager: bool, access_k: int, signal_tokens: int):
-    state = state_ref[...]          # (bs, n, m) int32
-    version = version_ref[...]      # (bs, m)
-    sync = sync_ref[...]            # (bs, n, m)
-    reads = reads_ref[...]          # (bs, n, m)
-    acts = act_ref[...]             # (bs, n)
-    arts = art_ref[...]             # (bs, n)
-    writes = write_ref[...]         # (bs, n)
-    bs = state.shape[0]
-    counters = jnp.zeros((bs, N_COUNTERS), jnp.int32)
-    miss_mat = jnp.zeros((bs, n_agents), jnp.int32)
+    # Every value is rank 3 with the sim axis leading: directory arrays
+    # (bs, n, m), per-artifact rows (bs, 1, m), per-agent rows (bs, 1, n),
+    # per-sim scalars (bs, 1, 1).  The carried directory lives in the
+    # output refs; the agent loop reads and rewrites them.
+    state_out[...] = state_ref[...]
+    version_out[...] = version_ref[...]
+    sync_out[...] = sync_ref[...]
+    reads_out[...] = reads_ref[...]
+    counter_out[...] = jnp.zeros(counter_out.shape, jnp.int32)
+    miss_out[...] = jnp.zeros(miss_out.shape, jnp.int32)
+    bs = state_ref.shape[0]
+    agent_col = jax.lax.broadcasted_iota(jnp.int32, (bs, n_agents, 1), 1)
+    agent_lane = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, n_agents), 2)
+    art_lane = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, n_artifacts), 2)
 
     def agent_body(a, carry):
-        state, version, sync, reads, counters, miss_mat = carry
-        act = acts[:, a] != 0                       # (bs,)
-        is_write = jnp.logical_and(act, writes[:, a] != 0)
-        is_read = jnp.logical_and(act, writes[:, a] == 0)
-        d_oh = (jax.lax.broadcasted_iota(jnp.int32, (bs, n_artifacts), 1)
-                == arts[:, a][:, None])             # (bs, m) one-hot
+        row = agent_col == a                        # (bs, n, 1)
+        a_oh = agent_lane == a                      # (bs, 1, n)
+        act = pick_lane(a_oh, act_ref[...]) != 0          # (bs, 1, 1)
+        wr = pick_lane(a_oh, write_ref[...]) != 0
+        is_write = jnp.logical_and(act, wr)
+        is_read = jnp.logical_and(act, jnp.logical_not(wr))
+        d_oh = art_lane == pick_lane(a_oh, art_ref[...])  # (bs, 1, m)
 
-        st_a = state[:, a, :]                       # (bs, m)
-        entry = jnp.sum(jnp.where(d_oh, st_a, 0), axis=1)        # (bs,)
-        reads_at = jnp.sum(jnp.where(d_oh, reads[:, a, :], 0), axis=1)
-        ver_at = jnp.sum(jnp.where(d_oh, version, 0), axis=1)
-
-        expired = jnp.zeros_like(entry, jnp.bool_)
+        state = state_out[...]
+        sync = sync_out[...]
+        reads = reads_out[...]
+        version = version_out[...]                  # (bs, 1, m)
+        miss = pick_lane(d_oh, pick_row(row, state)) == _I    # (bs, 1, 1)
         if access_k > 0:
-            expired = reads_at >= access_k
-        miss = jnp.logical_and(act, jnp.logical_or(entry == _I, expired))
+            miss = jnp.logical_or(
+                miss, pick_lane(d_oh, pick_row(row, reads)) >= access_k)
+        miss = jnp.logical_and(act, miss)
         hit = jnp.logical_and(act, jnp.logical_not(miss))
+        miss_i = miss.astype(jnp.int32)
 
-        # --- coherence fill on miss (read-modify-write prologue)
-        fill = jnp.logical_and(miss[:, None], d_oh)
-        st_a = jnp.where(fill, _S, st_a)
-        sy_a = jnp.where(fill, version, sync[:, a, :])
-        rd_a = jnp.where(fill, 0, reads[:, a, :])
-        counters = counters.at[:, 0].add(jnp.where(
-            miss, artifact_tokens + signal_tokens, 0))
-        counters = counters.at[:, 3].add(miss.astype(jnp.int32))
-        counters = counters.at[:, 4].add(hit.astype(jnp.int32))
-        miss_mat = miss_mat.at[:, a].set(miss.astype(jnp.int32))
-
-        state = state.at[:, a, :].set(st_a)
-        sync = sync.at[:, a, :].set(sy_a)
-        reads = reads.at[:, a, :].set(rd_a)
+        # --- coherence fill on miss (row a, chosen artifact)
+        fill = jnp.logical_and(row, jnp.logical_and(miss, d_oh))
+        state = jnp.where(fill, _S, state)
+        sync = jnp.where(fill, version, sync)
+        reads = jnp.where(fill, 0, reads)
+        counters = counter_out[...]
+        counters = slot_add(counters, 0,
+                             (artifact_tokens + signal_tokens) * miss_i)
+        counters = slot_add(counters, 3, miss_i)
+        counters = slot_add(counters, 4, hit.astype(jnp.int32))
+        miss_out[...] = jnp.where(a_oh, miss_i, miss_out[...])
 
         # --- write path: invalidate peers, bump version, commit
-        agent_ids = jax.lax.broadcasted_iota(
-            jnp.int32, (bs, n_agents, n_artifacts), 1)
-        peer = agent_ids != a                       # (bs, n, m)
-        wmask = jnp.logical_and(is_write[:, None, None], d_oh[:, None, :])
+        wmask = jnp.logical_and(is_write, d_oh)     # (bs, 1, m)
         peer_valid = jnp.logical_and(
-            jnp.logical_and(wmask, peer), state != _I)
-        n_peers = jnp.sum(peer_valid.astype(jnp.int32), axis=(1, 2))
-        counters = counters.at[:, 1].add(signal_tokens * n_peers)
-        counters = counters.at[:, 5].add(n_peers)
+            jnp.logical_and(wmask, jnp.logical_not(row)), state != _I)
+        n_peers = jnp.sum(jnp.sum(peer_valid.astype(jnp.int32), axis=2,
+                                  keepdims=True), axis=1, keepdims=True)
+        counters = slot_add(counters, 1, signal_tokens * n_peers)
+        counters = slot_add(counters, 5, n_peers)
         state = jnp.where(peer_valid, _I, state)
 
-        new_ver = jnp.where(jnp.logical_and(is_write[:, None], d_oh),
-                            version + 1, version)
-        writer = jnp.logical_and(wmask, jnp.logical_not(peer))
+        new_ver = jnp.where(wmask, version + 1, version)
+        writer = jnp.logical_and(wmask, row)
         state = jnp.where(writer, _S, state)
-        sync = jnp.where(writer, new_ver[:, None, :], sync)
+        sync = jnp.where(writer, new_ver, sync)
         reads = jnp.where(writer, 0, reads)
-        version = new_ver
 
         if eager:
             # push-on-commit to active sharers
             state = jnp.where(peer_valid, _S, state)
-            sync = jnp.where(peer_valid, new_ver[:, None, :], sync)
+            sync = jnp.where(peer_valid, new_ver, sync)
             reads = jnp.where(peer_valid, 0, reads)
-            counters = counters.at[:, 2].add(
-                (artifact_tokens + signal_tokens) * n_peers)
+            counters = slot_add(
+                counters, 2, (artifact_tokens + signal_tokens) * n_peers)
 
         # --- read bookkeeping
-        rmask = jnp.logical_and(is_read[:, None, None], d_oh[:, None, :])
-        own = jnp.logical_and(rmask, jnp.logical_not(peer))
+        own = jnp.logical_and(row, jnp.logical_and(is_read, d_oh))
         reads = jnp.where(own, reads + 1, reads)
-        return state, version, sync, reads, counters, miss_mat
 
-    state, version, sync, reads, counters, miss_mat = jax.lax.fori_loop(
-        0, n_agents, agent_body,
-        (state, version, sync, reads, counters, miss_mat))
-    state_out[...] = state
-    version_out[...] = version
-    sync_out[...] = sync
-    reads_out[...] = reads
-    counter_out[...] = counters
-    miss_out[...] = miss_mat
+        state_out[...] = state
+        sync_out[...] = sync
+        reads_out[...] = reads
+        version_out[...] = new_ver
+        counter_out[...] = counters
+        return carry
+
+    jax.lax.fori_loop(0, n_agents, agent_body, 0)
 
 
 def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
@@ -167,44 +190,33 @@ def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
     interpret = resolve_interpret(interpret)
     B, n, m = state.shape
     bs = min(block_sims, B)
+    # the kernel is rank 3 throughout: per-sim rows get a unit axis
+    args = [state, version[:, None, :], last_sync, reads_since_fetch,
+            acts[:, None, :], arts[:, None, :], writes[:, None, :]]
     pad = (-B) % bs
     if pad:
-        padded = []
-        for arr in (state, version, last_sync, reads_since_fetch,
-                    acts, arts, writes):
-            padded.append(jnp.pad(arr, [(0, pad)] + [(0, 0)] *
-                                  (arr.ndim - 1)))
-        state, version, last_sync, reads_since_fetch, acts, arts, writes \
-            = padded
-    Bp = state.shape[0]
-    grid = (Bp // bs,)
+        args = [jnp.pad(x, [(0, pad), (0, 0), (0, 0)]) for x in args]
+    Bp = B + pad
     kernel = functools.partial(
         _mesi_kernel, n_agents=n, n_artifacts=m,
         artifact_tokens=artifact_tokens, eager=eager, access_k=access_k,
         signal_tokens=signal_tokens)
-    spec3 = pl.BlockSpec((bs, n, m), lambda i: (i, 0, 0))
-    spec2n = pl.BlockSpec((bs, n), lambda i: (i, 0))
-    spec2m = pl.BlockSpec((bs, m), lambda i: (i, 0))
+    blocks = [(bs, n, m), (bs, 1, m), (bs, n, m), (bs, n, m),
+              (bs, 1, n), (bs, 1, n), (bs, 1, n)]
+    out_blocks = [(bs, n, m), (bs, 1, m), (bs, n, m), (bs, n, m),
+                  (bs, 1, N_COUNTERS), (bs, 1, n)]
+    spec = lambda blk: pl.BlockSpec(blk, lambda i: (i, 0, 0))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[spec3, spec2m, spec3, spec3, spec2n, spec2n, spec2n],
-        out_specs=[spec3, spec2m, spec3, spec3,
-                   pl.BlockSpec((bs, N_COUNTERS), lambda i: (i, 0)),
-                   spec2n],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, n, m), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, m), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, n, m), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, n, m), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, N_COUNTERS), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, n), jnp.int32),
-        ],
+        grid=(Bp // bs,),
+        in_specs=[spec(b) for b in blocks],
+        out_specs=[spec(b) for b in out_blocks],
+        out_shape=[jax.ShapeDtypeStruct((Bp,) + b[1:], jnp.int32)
+                   for b in out_blocks],
         interpret=interpret,
-    )(state, version, last_sync, reads_since_fetch, acts, arts, writes)
-    if pad:
-        out = tuple(o[:B] for o in out)
-    return out
+    )(*args)
+    st, ver, sy, rd, cnt, miss = (o[:B] for o in out)
+    return st, ver[:, 0], sy, rd, cnt[:, 0], miss[:, 0]
 
 
 def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
@@ -257,7 +269,8 @@ def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
         tile(jnp.asarray(arts, jnp.int32)),
         tile(jnp.asarray(writes, jnp.int32)),
         artifact_tokens=artifact_tokens, eager=eager, access_k=access_k,
-        signal_tokens=signal_tokens, block_sims=B, interpret=interpret)
+        signal_tokens=signal_tokens, block_sims=DECISION_BLOCK,
+        interpret=interpret)
     cnt_np = np.asarray(cnt, np.int64)
     arts_np = np.asarray(arts, np.int64)
     sync_np = np.asarray(sy, np.int64)

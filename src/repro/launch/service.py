@@ -31,6 +31,7 @@ import json
 import pathlib
 from typing import Optional
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service import (CoherenceBroker, CoherenceConfig, connect,
                            drive_workload, verify_broker)
 from repro.sim import workloads
@@ -277,4 +278,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    # here and not in main(): tests call main(argv) and must not turn
+    # the persistent cache on in their process
+    enable_compile_cache()
     main()

@@ -64,27 +64,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:  # newer jax exposes shard_map at the top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # jax <= 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _make_shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication check disabled, across jax's API
-    drift (``check_rep`` -> ``check_vma`` -> possibly neither).  The
-    check must be off where supported: the grid body is collective-free
-    (episodes are independent) and older jax has no replication rule
-    for ``pallas_call``, so the per-device MESI-tick kernel route would
-    be rejected under a checked shard_map."""
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map_impl(fn, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
-
 from repro.content.chunks import BYTES_PER_TOKEN
 from repro.core import acs
 from repro.core.states import MESIState
@@ -288,8 +267,10 @@ def _shard_wrap(run_grid, plan: ShardPlan, n_cell_operands: int,
         in_specs = (P("workloads"),) * n_cell_operands + (P(),)
         out_specs = P(None, "workloads", None)
     return jax.jit(
-        _make_shard_map(run_grid, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs),
+        # check_vma off: the grid body is collective-free (episodes are
+        # independent) and pallas_call has no varying-axes rule
+        jax.shard_map(run_grid, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
         donate_argnums=donate)
 
 
@@ -381,6 +362,8 @@ class Comparison:
     crr: float           # Coherence Reduction Ratio (SS8.2)
     chr_mean: float
     chr_std: float
+    #: per-run total tokens of the coherent variant, in run order
+    coherent_per_run_tokens: tuple = ()
 
 
 # ---------------------------------------------------------------------------
@@ -773,6 +756,8 @@ def _comparison_of(name: str, volatility: float, bc: RunResult,
         crr=co.stats.total_tokens_mean / bc.stats.total_tokens_mean,
         chr_mean=co.stats.cache_hit_rate_mean,
         chr_std=co.stats.cache_hit_rate_std,
+        coherent_per_run_tokens=tuple(
+            int(t) for t in co.per_run_total_tokens),
     )
 
 

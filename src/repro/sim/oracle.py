@@ -45,6 +45,7 @@ bit-identical byte ledgers, ``delta <= full`` per fill.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -220,23 +221,39 @@ def replay_protocol(cfg: acs.ACSConfig, trace: Trace):
 
 
 # ---------------------------------------------------------------------------
-# Leg 2: vectorized JAX state machine (eager replay of the tick bodies).
+# Leg 2: vectorized JAX state machine (per-action replay of the tick bodies).
 
 
-def replay_vectorized(cfg: acs.ACSConfig, trace: Trace):
-    content = acs.content_enabled(cfg)
-    arrays = acs.init_arrays(cfg)
-    met = acs.init_metrics()
-    for s, a, d, is_write in _actions(trace):
+@functools.lru_cache(maxsize=None)
+def _action_step(cfg: acs.ACSConfig):
+    """One serialized action through the production ``acs._do_read`` /
+    ``_do_write`` bodies, compiled once per static config with the agent
+    and artifact traced.  Run eagerly, each action re-traced and
+    recompiled its ``lax.cond`` (about 76 ms an action on a CPU)."""
+
+    def step(arrays, met, a, d, wchunks, *, is_write):
         arrays = arrays._replace(
             agent_actions=arrays.agent_actions.at[a].add(1))
         if is_write:
-            wchunks = (jnp.asarray(trace.write_chunks[s, a])
-                       if content else None)
-            arrays, met = acs._do_write(cfg, arrays, met, a, d,
-                                        wchunks=wchunks)
-        else:
-            arrays, met = acs._do_read(cfg, arrays, met, a, d)
+            return acs._do_write(cfg, arrays, met, a, d, wchunks=wchunks)
+        return acs._do_read(cfg, arrays, met, a, d)
+
+    return jax.jit(step, static_argnames="is_write")
+
+
+def _replay_action(cfg, arrays, met, trace: Trace, s, a, d, is_write):
+    wchunks = (jnp.asarray(trace.write_chunks[s, a])
+               if is_write and acs.content_enabled(cfg) else None)
+    return _action_step(cfg)(arrays, met, a, d, wchunks,
+                             is_write=is_write)
+
+
+def replay_vectorized(cfg: acs.ACSConfig, trace: Trace):
+    arrays = acs.init_arrays(cfg)
+    met = acs.init_metrics()
+    for s, a, d, is_write in _actions(trace):
+        arrays, met = _replay_action(cfg, arrays, met, trace, s, a, d,
+                                     is_write)
     ledger = Ledger(
         fetch_tokens=int(met.fetch_tokens),
         signal_tokens=int(met.signal_tokens),
@@ -581,7 +598,7 @@ def _content_cfg_check(cfg: acs.ACSConfig) -> None:
 
 
 def replay_content_vectorized(cfg: acs.ACSConfig, trace: Trace):
-    """Eager replay of the content plane through the production
+    """Per-action replay of the content plane through the production
     ``acs._do_read`` / ``_do_write`` bodies.
 
     Returns ``(ByteLedger, chunk_version, chunk_sync, chunk_dirty,
@@ -593,19 +610,13 @@ def replay_content_vectorized(cfg: acs.ACSConfig, trace: Trace):
     met = acs.init_metrics()
     fills = []
     for s, a, d, is_write in _actions(trace):
-        arrays = arrays._replace(
-            agent_actions=arrays.agent_actions.at[a].add(1))
         ver_b = np.asarray(arrays.chunk_version[d], np.int32)
         sync_b = np.asarray(arrays.chunk_sync[a, d], np.int32)
         dirty_b = np.asarray(arrays.chunk_dirty[d], np.int32)
         before = (int(met.n_fetches), int(met.delta_bytes),
                   int(met.full_bytes))
-        if is_write:
-            arrays, met = acs._do_write(
-                cfg, arrays, met, a, d,
-                wchunks=jnp.asarray(trace.write_chunks[s, a]))
-        else:
-            arrays, met = acs._do_read(cfg, arrays, met, a, d)
+        arrays, met = _replay_action(cfg, arrays, met, trace, s, a, d,
+                                     is_write)
         if int(met.n_fetches) > before[0]:
             fills.append(FillEvent(
                 step=s, agent=a, artifact=d,
