@@ -172,6 +172,7 @@ def chunk_tick_pallas(chunk_version, chunk_sync, chunk_dirty,
         out_shape=[jax.ShapeDtypeStruct((Bp,) + b[1:], jnp.int32)
                    for b in out_blocks],
         interpret=interpret,
+        name="chunk_tick",
     )(*args)
     cv, cs, dirty, fetched, counters = (o[:B] for o in out)
     return cv, cs, dirty, fetched, counters[:, 0]

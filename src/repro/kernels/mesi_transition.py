@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.states import MESIState
 from repro.kernels.backend import resolve_interpret
+from repro.obs.spans import span
 
 _I, _S = int(MESIState.I), int(MESIState.S)
 N_COUNTERS = 8
@@ -214,6 +215,7 @@ def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
         out_shape=[jax.ShapeDtypeStruct((Bp,) + b[1:], jnp.int32)
                    for b in out_blocks],
         interpret=interpret,
+        name="mesi_tick",
     )(*args)
     st, ver, sy, rd, cnt, miss = (o[:B] for o in out)
     return st, ver[:, 0], sy, rd, cnt[:, 0], miss[:, 0]
@@ -254,35 +256,45 @@ def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
         zc = jnp.zeros((N_COUNTERS,), jnp.int32)
         return (state, version, last_sync, reads_since_fetch, zc,
                 jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32))
-    # sim j enables the first j requests; sim 0 is the no-op baseline.
-    # B is padded to the FIXED n+1 (rows past k repeat the full batch,
-    # so their counter deltas are zero) - every micro-batch size shares
-    # one compiled program instead of one Mosaic compile per distinct k.
-    B = n + 1
-    acts_b = np.zeros((B, n), np.int32)
-    for j, a in enumerate(order):
-        acts_b[j + 1:, a] = 1
-    tile = lambda arr: jnp.broadcast_to(arr, (B,) + arr.shape)
-    st, ver, sy, rd, cnt, _ = mesi_tick_pallas(
-        tile(state), tile(version), tile(last_sync),
-        tile(reads_since_fetch), jnp.asarray(acts_b),
-        tile(jnp.asarray(arts, jnp.int32)),
-        tile(jnp.asarray(writes, jnp.int32)),
-        artifact_tokens=artifact_tokens, eager=eager, access_k=access_k,
-        signal_tokens=signal_tokens, block_sims=DECISION_BLOCK,
-        interpret=interpret)
-    cnt_np = np.asarray(cnt, np.int64)
-    arts_np = np.asarray(arts, np.int64)
-    sync_np = np.asarray(sy, np.int64)
-    miss = np.zeros((n,), bool)
-    served = np.zeros((n,), np.int32)
-    for j, a in enumerate(order):
-        # counter slot 3 = n_fetches; the delta between prefix j+1 and
-        # prefix j is exactly request j's fill.
-        miss[a] = (cnt_np[j + 1, 3] - cnt_np[j, 3]) == 1
-        # sim j+1 processed request j last: its sync cell is the version
-        # agent a is synced to at its serialization slot (later eager
-        # pushes in the full batch must not leak into this answer).
-        served[a] = sync_np[j + 1, a, arts_np[a]]
-    return (st[-1], ver[-1], sy[-1], rd[-1], cnt[-1],
-            jnp.asarray(miss), jnp.asarray(served))
+    # The served decide's phases are with-blocks in place (the kernel's
+    # source locations carry the whole stack: a frame more here costs
+    # lowering time on every batch).
+    with span("broker.decide.stage"):
+        # sim j enables the first j requests; sim 0 is the no-op
+        # baseline.  B is padded to the FIXED n+1 (rows past k repeat
+        # the full batch, so their counter deltas are zero) - every
+        # micro-batch size shares one compiled program instead of one
+        # Mosaic compile per distinct k.
+        B = n + 1
+        acts_b = np.zeros((B, n), np.int32)
+        for j, a in enumerate(order):
+            acts_b[j + 1:, a] = 1
+        tile = lambda arr: jnp.broadcast_to(arr, (B,) + arr.shape)
+        args = (tile(state), tile(version), tile(last_sync),
+                tile(reads_since_fetch), jnp.asarray(acts_b),
+                tile(jnp.asarray(arts, jnp.int32)),
+                tile(jnp.asarray(writes, jnp.int32)))
+    with span("broker.decide.call"):
+        st, ver, sy, rd, cnt, _ = mesi_tick_pallas(
+            *args, artifact_tokens=artifact_tokens, eager=eager,
+            access_k=access_k, signal_tokens=signal_tokens,
+            block_sims=DECISION_BLOCK, interpret=interpret)
+        full = (st[-1], ver[-1], sy[-1], rd[-1], cnt[-1])
+        del args        # the n+1 replicas are released here
+    with span("broker.decide.readback"):
+        cnt_np = np.asarray(cnt, np.int64)
+        sync_np = np.asarray(sy, np.int64)
+    with span("broker.decide.outcomes"):
+        arts_np = np.asarray(arts, np.int64)
+        miss = np.zeros((n,), bool)
+        served = np.zeros((n,), np.int32)
+        for j, a in enumerate(order):
+            # counter slot 3 = n_fetches; the delta between prefix j+1
+            # and prefix j is exactly request j's fill.
+            miss[a] = (cnt_np[j + 1, 3] - cnt_np[j, 3]) == 1
+            # sim j+1 processed request j last: its sync cell is the
+            # version agent a is synced to at its serialization slot
+            # (later eager pushes in the full batch must not leak into
+            # this answer).
+            served[a] = sync_np[j + 1, a, arts_np[a]]
+        return full + (jnp.asarray(miss), jnp.asarray(served))

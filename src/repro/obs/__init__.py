@@ -9,10 +9,12 @@ metrics for the live coherence service:
     facade: one ``record_batch`` hook per committed micro-batch feeds
     the MESI detectors (invalidation events/storms, ping-pong,
     staleness-at-serve, state occupancy) and the span recorder;
-  * :mod:`repro.obs.spans` - Chrome trace-event export
-    (``chrome://tracing`` / Perfetto flame graphs);
-  * :mod:`repro.obs.runtime` - process-global jit/Pallas compile-event
-    log (trace-time side-effect accounting, engine-style);
+  * :mod:`repro.obs.spans` - phase spans on the profiler's clock, one
+    record per committed micro-batch (or sweep call), with Chrome
+    trace-event export (``chrome://tracing`` / Perfetto flame graphs);
+  * :mod:`repro.obs.runtime` - the process-wide ``jax.monitoring``
+    listener that charges trace / lower / compile time to the open
+    record, its build-event log, and the sweep-call records;
   * :mod:`repro.obs.stats` - the unified ``stats()`` schema both
     broker flavors serve (with the legacy flat-key deprecation shim);
   * :mod:`repro.obs.conformance` - the ``MetricsConformance`` oracle
@@ -31,14 +33,15 @@ from repro.obs.conformance import (CONFORMANCE_COUNTERS,
 from repro.obs.registry import (Counter, Gauge, Histogram,
                                 MetricsRegistry)
 from repro.obs.runtime import (compile_count, compile_events,
-                               note_compile, note_warmup,
-                               reset_compile_log)
-from repro.obs.spans import Span, SpanRecorder
+                               reset_compile_log, sweep_records)
+from repro.obs.spans import (BatchRecord, Record, Span, SpanRecorder,
+                             span)
 from repro.obs.stats import LEGACY_KEYS, StatsView, unified_stats
 from repro.obs.telemetry import BatchObservation, Telemetry
 
 __all__ = [
     "BatchObservation",
+    "BatchRecord",
     "CONFORMANCE_COUNTERS",
     "CONFORMANCE_HISTOGRAMS",
     "Counter",
@@ -47,6 +50,7 @@ __all__ = [
     "LEGACY_KEYS",
     "MetricsConformanceError",
     "MetricsRegistry",
+    "Record",
     "Span",
     "SpanRecorder",
     "StatsView",
@@ -54,9 +58,9 @@ __all__ = [
     "check_metrics_conformance",
     "compile_count",
     "compile_events",
-    "note_compile",
-    "note_warmup",
     "replay_telemetry",
     "reset_compile_log",
+    "span",
+    "sweep_records",
     "unified_stats",
 ]

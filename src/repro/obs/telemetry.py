@@ -34,9 +34,9 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from repro.core.states import MESIState
-from repro.obs import runtime
+from repro.obs import runtime, spans
 from repro.obs.registry import MetricsRegistry, _labelkey
-from repro.obs.spans import SpanRecorder
+from repro.obs.spans import BatchRecord, SpanRecorder
 
 _I = int(MESIState.I)
 _STATE_NAMES = {int(s): s.name for s in MESIState}
@@ -88,7 +88,7 @@ class BatchObservation:
     queue_depth: int = 0
     t_decide: float = 0.0
     t_respond: float = 0.0
-    t_submits: Optional[dict] = None    # agent -> t_submit
+    t_submit: Optional[np.ndarray] = None   # (n,) submit time per slot
     latencies: Optional[dict] = None    # agent -> latency_s
 
 
@@ -322,24 +322,21 @@ class Telemetry:
         for latency in (obs.latencies or {}).values():
             lat.observe(latency)
 
-        # one complete span per request + one per batch, recorded at
-        # resolve time (no open-span state on the hot path)
-        t_apply_end = obs.t_respond
-        decide_end = obs.t_decide + obs.busy_s
-        self.spans.add("decide", "batch", obs.t_decide, obs.busy_s,
-                       pid=shard, tid="authority",
-                       batch_size=batch_size, route=obs.route,
-                       queue_depth=obs.queue_depth)
-        arts = np.asarray(obs.arts)
-        for agent, t_submit in (obs.t_submits or {}).items():
-            name = obs.names[int(arts[agent])]
-            op = "write" if writes[agent] else "read"
-            self.spans.add(
-                f"{op} {name}", "request", t_submit,
-                t_apply_end - t_submit, pid=shard, tid=int(agent),
-                queue_s=max(0.0, obs.t_decide - t_submit),
-                decide_s=obs.busy_s,
-                apply_s=max(0.0, t_apply_end - decide_end))
+        # the batch's requests go on its record, whose spans are
+        # derived when read (nothing is appended per request)
+        rec = spans.current()
+        if isinstance(rec, BatchRecord):
+            rec.shard = shard
+            rec.names = obs.names
+            rec.acts = obs.acts
+            rec.arts = obs.arts
+            rec.writes = writes
+            rec.t_submit = obs.t_submit
+            rec.t_decide = obs.t_decide
+            rec.decide_s = obs.busy_s
+            rec.t_respond = obs.t_respond
+            rec.route = obs.route
+            rec.queue_depth = obs.queue_depth
 
     # --------------------------------------------------------- L1 plane
     def record_l1_fill(self, host: int, level: str, nbytes: int) -> None:
@@ -375,12 +372,13 @@ class Telemetry:
         return self.registry.to_prometheus()
 
     def chrome_trace(self) -> dict:
+        """The span records and the build events, on one perf_counter
+        axis."""
         trace = self.spans.chrome_trace()
-        shift = runtime.epoch() - self.spans.epoch
         for e in runtime.compile_events():
             trace["traceEvents"].append({
                 "name": f"{e['kind']}:{e['route']}", "cat": "compile",
-                "ph": "X", "ts": (e["t_s"] + shift) * 1e6,
+                "ph": "X", "ts": (e["t_s"] - self.spans.epoch) * 1e6,
                 "dur": e["dur_s"] * 1e6, "pid": -1, "tid": "jit",
                 "args": {"label": e["label"]}})
         return trace

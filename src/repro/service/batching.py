@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 from typing import NamedTuple
 
 import jax
@@ -40,11 +39,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import acs
-from repro.obs import runtime as obs_runtime
 from repro.kernels.backend import interpret_default
 from repro.kernels.chunk_diff import (chunk_tick_pallas, chunk_tick_ref,
                                       resolve_chunk_route)
 from repro.kernels.mesi_transition import mesi_decision_batch
+from repro.obs.spans import span
 
 #: strategies the kernel route supports (== oracle DIFFERENTIAL scope).
 KERNEL_STRATEGIES = (acs.LAZY, acs.EAGER, acs.ACCESS_COUNT)
@@ -97,19 +96,12 @@ def _scan_decider(cfg: acs.ACSConfig):
     configs the pass also carries the content plane (the per-agent
     dirty chunk masks become a traced operand)."""
 
-    label = (f"agents={cfg.n_agents} artifacts={cfg.n_artifacts} "
-             f"strategy={acs.STRATEGY_NAMES[cfg.strategy]}")
-
     if acs.content_enabled(cfg):
         def fn(arrays, met, acts, arts, writes, write_chunks):
-            # trace-time side effect: fires once per (re)trace, never
-            # during compiled execution (engine trace-counter pattern)
-            obs_runtime.note_compile("scan", label)
             return acs.apply_actions(cfg, arrays, met, acts, arts,
                                      writes, write_chunks=write_chunks)
     else:
         def fn(arrays, met, acts, arts, writes):
-            obs_runtime.note_compile("scan", label)
             return acs.apply_actions(cfg, arrays, met, acts, arts,
                                      writes)
 
@@ -154,7 +146,6 @@ class BatchDecider:
             self.metrics = jax.device_put(self.metrics, device)
         self._scan = _scan_decider(cfg) if self.backend == "scan" else None
         self._deciding = False
-        self._warmed = False
 
     # ------------------------------------------------------------------
     def decide(self, acts: np.ndarray, arts: np.ndarray,
@@ -172,51 +163,56 @@ class BatchDecider:
         if acs.content_enabled(self.cfg) and write_chunks is None:
             raise ValueError("chunked decider needs write_chunks masks")
         self._deciding = True
-        t0 = time.perf_counter()
         try:
             if self.backend == "scan":
                 return self._decide_scan(acts, arts, writes,
                                          write_chunks)
             return self._decide_pallas(acts, arts, writes, write_chunks)
         finally:
-            if not self._warmed:
-                # first-call wall time = compile + first dispatch (the
-                # portable proxy for Pallas lowering, which happens
-                # inside pallas_call where we own no Python body)
-                self._warmed = True
-                obs_runtime.note_warmup(
-                    self.backend, time.perf_counter() - t0,
-                    f"agents={self.cfg.n_agents} "
-                    f"artifacts={self.cfg.n_artifacts}")
             self._deciding = False
 
     # ------------------------------------------------------------------
+    # The phases below are with-blocks in place: stage (host arrays to
+    # the device), call (the jitted pass or kernel, until it returns:
+    # trace, lower, compile or load, dispatch), readback (outputs to the
+    # host: device wait and transfer) and outcomes (host derivation of
+    # per-request outcomes and ledger deltas).
     def _decide_scan(self, acts, arts, writes,
                      write_chunks) -> BatchDecision:
         content = acs.content_enabled(self.cfg)
-        before = {f: int(getattr(self.metrics, f))
-                  for f in _LEDGER_FIELDS + (_WIRE_FIELDS if content
-                                             else ())}
-        args = [self.arrays, self.metrics, jnp.asarray(acts, bool),
-                jnp.asarray(arts, jnp.int32), jnp.asarray(writes, bool)]
-        if content:
-            args.append(jnp.asarray(write_chunks, bool))
-        self.arrays, self.metrics, out = self._scan(*args)
-        delta = {f: int(getattr(self.metrics, f)) - before[f]
-                 for f in _LEDGER_FIELDS}
-        wire = ({f: int(getattr(self.metrics, f)) - before[f]
-                 for f in _WIRE_FIELDS} if content else None)
-        return BatchDecision(
-            miss=np.asarray(out.miss, bool),
-            version=np.asarray(out.version, np.int32),
-            ledger_delta=delta,
-            fetched_chunks=(np.asarray(out.fetched_chunks, bool)
-                            if content else None),
-            wire_delta=wire)
+        fields = _LEDGER_FIELDS + (_WIRE_FIELDS if content else ())
+        with span("broker.decide.readback"):
+            before = {f: int(getattr(self.metrics, f)) for f in fields}
+        with span("broker.decide.stage"):
+            batch = [jnp.asarray(acts, bool), jnp.asarray(arts, jnp.int32),
+                     jnp.asarray(writes, bool)]
+            if content:
+                batch.append(jnp.asarray(write_chunks, bool))
+        with span("broker.decide.call"):
+            # the previous directory is released here, not at return
+            self.arrays, self.metrics, out = self._scan(
+                self.arrays, self.metrics, *batch)
+            del batch
+        with span("broker.decide.readback"):
+            after = {f: int(getattr(self.metrics, f)) for f in fields}
+            miss = np.asarray(out.miss, bool)
+            version = np.asarray(out.version, np.int32)
+            fetched = (np.asarray(out.fetched_chunks, bool)
+                       if content else None)
+            del out
+        with span("broker.decide.outcomes"):
+            delta = {f: after[f] - before[f] for f in _LEDGER_FIELDS}
+            wire = ({f: after[f] - before[f] for f in _WIRE_FIELDS}
+                    if content else None)
+            return BatchDecision(miss=miss, version=version,
+                                 ledger_delta=delta,
+                                 fetched_chunks=fetched, wire_delta=wire)
 
     def _decide_pallas(self, acts, arts, writes,
                        write_chunks) -> BatchDecision:
         a = self.arrays
+        # mesi_decision_batch times its own stage, call, readback and
+        # outcomes
         st, ver, sy, rd, cnt, miss, served = mesi_decision_batch(
             a.state, a.version, a.last_sync, a.reads_since_fetch,
             np.asarray(acts, bool), np.asarray(arts, np.int32),
@@ -228,53 +224,66 @@ class BatchDecider:
             signal_tokens=acs.SIGNAL_TOKENS)
         acts_np = np.asarray(acts, bool)
         writes_np = np.asarray(writes, bool)
-        cnt_np = np.asarray(cnt, np.int64)
-        delta = {f: int(cnt_np[slot])
-                 for f, slot in _KERNEL_SLOTS.items()}
-        # the kernel tracks token counters only; action counts come from
-        # the batch itself (same derivation as oracle.replay_pallas).
-        delta["n_reads"] = int((acts_np & ~writes_np).sum())
-        delta["n_writes"] = int((acts_np & writes_np).sum())
-        # agent_actions is a scan-path diagnostic (staleness clocks);
-        # each acting agent performed exactly one action this batch.
-        self.arrays = a._replace(
-            state=st, version=ver, last_sync=sy, reads_since_fetch=rd,
-            agent_actions=a.agent_actions + jnp.asarray(acts_np, jnp.int32))
-        self.metrics = self.metrics._replace(**{
-            f: getattr(self.metrics, f) + delta[f]
-            for f in _LEDGER_FIELDS})
+        with span("broker.decide.readback"):
+            cnt_np = np.asarray(cnt, np.int64)
+        with span("broker.decide.outcomes"):
+            delta = {f: int(cnt_np[slot])
+                     for f, slot in _KERNEL_SLOTS.items()}
+            # the kernel tracks token counters only; action counts come
+            # from the batch itself (same derivation as
+            # oracle.replay_pallas).
+            delta["n_reads"] = int((acts_np & ~writes_np).sum())
+            delta["n_writes"] = int((acts_np & writes_np).sum())
+            # agent_actions is a scan-path diagnostic (staleness clocks);
+            # each acting agent performed exactly one action this batch.
+            self.arrays = a._replace(
+                state=st, version=ver, last_sync=sy,
+                reads_since_fetch=rd,
+                agent_actions=a.agent_actions + jnp.asarray(acts_np,
+                                                            jnp.int32))
+            del a       # the previous directory is released here
+            self.metrics = self.metrics._replace(**{
+                f: getattr(self.metrics, f) + delta[f]
+                for f in _LEDGER_FIELDS})
         fetched = wire = None
         if acs.content_enabled(self.cfg):
             # Content plane rides the same serialization order: the
             # chunk tick consumes the per-request miss bits and the
             # measured dirty masks.  REPRO_CHUNK_DIFF=scan forces the
             # pure-jnp reference (bit-identical; oracle-checked).
-            tick = (chunk_tick_ref
-                    if resolve_chunk_route("pallas") == "scan"
-                    else chunk_tick_pallas)
-            wact = (acts_np & writes_np).astype(np.int32)
-            cv, cs, dirty, fetched_b, ccnt = tick(
-                self.arrays.chunk_version[None],
-                self.arrays.chunk_sync[None],
-                self.arrays.chunk_dirty[None],
-                np.asarray(miss, np.int32)[None], wact[None],
-                np.asarray(arts, np.int32)[None],
-                np.asarray(write_chunks, np.int32)[None],
-                artifact_tokens=self.cfg.artifact_tokens,
-                chunk_tokens=self.cfg.chunk_tokens,
-                signal_tokens=acs.SIGNAL_TOKENS)
-            self.arrays = self.arrays._replace(
-                chunk_version=cv[0], chunk_sync=cs[0],
-                chunk_dirty=dirty[0])
-            ccnt_np = np.asarray(ccnt[0], np.int64)
-            wire = {"delta_bytes": int(ccnt_np[0]),
-                    "full_bytes": int(ccnt_np[1]),
-                    "n_chunks_fetched": int(ccnt_np[2])}
-            self.metrics = self.metrics._replace(**{
-                f: getattr(self.metrics, f) + wire[f]
-                for f in _WIRE_FIELDS})
-            fetched = np.asarray(fetched_b[0], bool)
-        return BatchDecision(miss=np.asarray(miss, bool),
-                             version=np.asarray(served, np.int32),
-                             ledger_delta=delta,
-                             fetched_chunks=fetched, wire_delta=wire)
+            with span("broker.decide.stage"):
+                tick = (chunk_tick_ref
+                        if resolve_chunk_route("pallas") == "scan"
+                        else chunk_tick_pallas)
+                wact = (acts_np & writes_np).astype(np.int32)
+                chunk_args = (
+                    self.arrays.chunk_version[None],
+                    self.arrays.chunk_sync[None],
+                    self.arrays.chunk_dirty[None],
+                    np.asarray(miss, np.int32)[None], wact[None],
+                    np.asarray(arts, np.int32)[None],
+                    np.asarray(write_chunks, np.int32)[None])
+            with span("broker.decide.call"):
+                cv, cs, dirty, fetched_b, ccnt = tick(
+                    *chunk_args,
+                    artifact_tokens=self.cfg.artifact_tokens,
+                    chunk_tokens=self.cfg.chunk_tokens,
+                    signal_tokens=acs.SIGNAL_TOKENS)
+            with span("broker.decide.readback"):
+                ccnt_np = np.asarray(ccnt[0], np.int64)
+                fetched = np.asarray(fetched_b[0], bool)
+            with span("broker.decide.outcomes"):
+                self.arrays = self.arrays._replace(
+                    chunk_version=cv[0], chunk_sync=cs[0],
+                    chunk_dirty=dirty[0])
+                wire = {"delta_bytes": int(ccnt_np[0]),
+                        "full_bytes": int(ccnt_np[1]),
+                        "n_chunks_fetched": int(ccnt_np[2])}
+                self.metrics = self.metrics._replace(**{
+                    f: getattr(self.metrics, f) + wire[f]
+                    for f in _WIRE_FIELDS})
+        with span("broker.decide.readback"):
+            return BatchDecision(miss=np.asarray(miss, bool),
+                                 version=np.asarray(served, np.int32),
+                                 ledger_delta=delta,
+                                 fetched_chunks=fetched, wire_delta=wire)

@@ -41,6 +41,7 @@ from repro.core import acs, invariants
 from repro.core.protocol import (ArtifactStore, EventBus, Message,
                                  TokenLedger)
 from repro.core.states import MESIState
+from repro.obs.spans import BATCH, span
 from repro.obs.stats import unified_stats
 from repro.obs.telemetry import BatchObservation, Telemetry
 from repro.service.batching import BatchDecider
@@ -414,15 +415,22 @@ class CoherenceBroker:
         return batch
 
     def _flush_once(self) -> None:
-        batch = self._cut_batch()
-        if not batch:
-            return
-        try:
-            self._decide_and_resolve(batch)
-        except Exception as e:       # noqa: BLE001 - fail the batch, not
-            for req in batch:        # the event loop
-                if not req.future.done():
-                    req.future.set_exception(e)
+        # one batch, from cut to the end of its telemetry; the phase
+        # spans are with-blocks in place (a frame more on the way to the
+        # kernel costs its lowering time)
+        tel = self.telemetry
+        with (tel.spans.batch(self.shard) if tel is not None
+              else span(BATCH)):
+            with span("broker.cut"):
+                batch = self._cut_batch()
+            if not batch:
+                return
+            try:
+                self._decide_and_resolve(batch)
+            except Exception as e:   # noqa: BLE001 - fail the batch,
+                for req in batch:    # not the event loop
+                    if not req.future.done():
+                        req.future.set_exception(e)
 
     def _measure_write_masks(self, batch: list) -> Optional[np.ndarray]:
         """(n, C) measured dirty chunk masks for the batch's writes.
@@ -454,112 +462,120 @@ class CoherenceBroker:
 
     def _decide_and_resolve(self, batch: list) -> None:
         n = self.config.n_agents
-        acts = np.zeros(n, bool)
-        arts = np.zeros(n, np.int32)
-        writes = np.zeros(n, bool)
-        for req in batch:
-            acts[req.agent] = True
-            arts[req.agent] = req.artifact
-            writes[req.agent] = req.is_write
-        wmasks = self._measure_write_masks(batch)
-
         tel = self.telemetry
-        state_before = (np.asarray(self.decider.arrays.state,
-                                   np.int32).copy()
-                        if tel is not None else None)
-        queue_depth = len(batch) + len(self._pending)
-        ver_before = np.asarray(self.decider.arrays.version,
-                                np.int64).copy()
-        t_decide = time.perf_counter()
-        decision = self.decider.decide(acts, arts, writes,
-                                       write_chunks=wmasks)
-        busy_s = time.perf_counter() - t_decide
+        with span("broker.stage"):
+            acts = np.zeros(n, bool)
+            arts = np.zeros(n, np.int32)
+            writes = np.zeros(n, bool)
+            t_submit = np.zeros(n)
+            for req in batch:
+                acts[req.agent] = True
+                arts[req.agent] = req.artifact
+                writes[req.agent] = req.is_write
+                t_submit[req.agent] = req.t_submit
+            wmasks = self._measure_write_masks(batch)
+            state_before = (np.asarray(self.decider.arrays.state,
+                                       np.int32).copy()
+                            if tel is not None else None)
+            queue_depth = len(batch) + len(self._pending)
+            ver_before = np.asarray(self.decider.arrays.version,
+                                    np.int64).copy()
+        with span("broker.decide"):
+            t_decide = time.perf_counter()
+            decision = self.decider.decide(acts, arts, writes,
+                                           write_chunks=wmasks)
+            busy_s = time.perf_counter() - t_decide
         self.decide_busy_s += busy_s
-        ver_after = np.asarray(self.decider.arrays.version, np.int64)
 
-        if self.config.check_invariants:
-            self._check_invariants(batch, ver_before, ver_after)
+        with span("broker.checks"):
+            ver_after = np.asarray(self.decider.arrays.version, np.int64)
+            if self.config.check_invariants:
+                self._check_invariants(batch, ver_before, ver_after)
 
-        # ledger: exact integer deltas from the decision engine
-        for field, delta in decision.ledger_delta.items():
-            setattr(self.ledger, field,
-                    getattr(self.ledger, field) + delta)
-        if decision.wire_delta is not None:
-            for field, delta in decision.wire_delta.items():
-                self.wire[field] += delta
+        with span("broker.respond"):
+            # ledger: exact integer deltas from the decision engine
+            for field, delta in decision.ledger_delta.items():
+                setattr(self.ledger, field,
+                        getattr(self.ledger, field) + delta)
+            if decision.wire_delta is not None:
+                for field, delta in decision.wire_delta.items():
+                    self.wire[field] += delta
 
-        # content plane + responses, in the authority's agent order
-        # (reads at slot a see commits from slots < a, exactly the
-        # order the decision plane serialized)
-        now = time.perf_counter()
-        latencies = {}
-        for req in sorted(batch, key=lambda r: r.agent):
-            name = self.names[req.artifact]
-            version = int(decision.version[req.agent])
-            latency = now - req.t_submit
-            latencies[req.agent] = latency
-            self.latencies.append(latency)
-            if req.is_write:
-                content = (list(req.content) if req.content is not None
-                           else list(self.store.get(name)))
-                dirty = None
-                if self.chunks is not None:
-                    self.chunks.put(name, content)
-                    dirty = tuple(np.flatnonzero(wmasks[req.agent])
-                                  .tolist())
+            # content plane + responses, in the authority's agent order
+            # (reads at slot a see commits from slots < a, exactly the
+            # order the decision plane serialized)
+            now = time.perf_counter()
+            latencies = {}
+            for req in sorted(batch, key=lambda r: r.agent):
+                name = self.names[req.artifact]
+                version = int(decision.version[req.agent])
+                latency = now - req.t_submit
+                latencies[req.agent] = latency
+                self.latencies.append(latency)
+                if req.is_write:
+                    content = (list(req.content)
+                               if req.content is not None
+                               else list(self.store.get(name)))
+                    dirty = None
+                    if self.chunks is not None:
+                        self.chunks.put(name, content)
+                        dirty = tuple(np.flatnonzero(wmasks[req.agent])
+                                      .tolist())
+                    else:
+                        self.store.put(name, content)
+                    self.bus.publish(Message(
+                        "VERSION_UPDATE", f"agent-{req.agent}", name,
+                        version, timestamp=now))
+                    req.future.set_result(WriteResult(
+                        version, latency, dirty_chunks=dirty))
                 else:
-                    self.store.put(name, content)
-                self.bus.publish(Message(
-                    "VERSION_UPDATE", f"agent-{req.agent}", name,
-                    version, timestamp=now))
-                req.future.set_result(WriteResult(version, latency,
-                                                  dirty_chunks=dirty))
-            else:
-                delta = None
-                delta_bytes = -1
-                if self.chunks is not None:
-                    fetched = np.flatnonzero(
-                        decision.fetched_chunks[req.agent])
-                    delta = self.chunks.delta(name, fetched)
-                    delta_bytes = 0
-                    if decision.miss[req.agent]:
-                        delta_bytes = (sum(len(c) for _, c in delta)
-                                       + acs.SIGNAL_TOKENS
-                                       ) * BYTES_PER_TOKEN
-                req.future.set_result(ReadResult(
-                    tuple(self.store.get(name)), version,
-                    hit=not bool(decision.miss[req.agent]),
-                    latency_s=latency, delta=delta,
-                    delta_bytes=delta_bytes))
-        self.n_batches += 1
-        if self.config.capture_trace:
-            self.trace.append_step(acts, arts, writes, decision.miss,
-                                   decision.version, latencies,
-                                   write_chunks=wmasks,
-                                   decide_s=busy_s,
-                                   batch_size=len(batch))
-        if tel is not None:
-            tel.record_batch(BatchObservation(
-                names=self.names, acts=acts, arts=arts, writes=writes,
-                miss=np.asarray(decision.miss, bool),
-                version=np.asarray(decision.version, np.int64),
-                ledger_delta=decision.ledger_delta,
-                state_before=state_before,
-                state_after=np.asarray(self.decider.arrays.state,
-                                       np.int32),
-                ver_after=ver_after,
-                wire_delta=decision.wire_delta,
-                shard=self.shard, live=True, busy_s=busy_s,
-                route=self.decider.backend, queue_depth=queue_depth,
-                t_decide=t_decide, t_respond=now,
-                t_submits={req.agent: req.t_submit for req in batch},
-                latencies=latencies))
-        if self._on_commit is not None:
-            self._on_commit(self, {
-                "acts": acts, "arts": arts, "writes": writes,
-                "miss": decision.miss, "version": decision.version,
-                "latencies": latencies, "write_chunks": wmasks,
-                "busy_s": busy_s})
+                    delta = None
+                    delta_bytes = -1
+                    if self.chunks is not None:
+                        fetched = np.flatnonzero(
+                            decision.fetched_chunks[req.agent])
+                        delta = self.chunks.delta(name, fetched)
+                        delta_bytes = 0
+                        if decision.miss[req.agent]:
+                            delta_bytes = (sum(len(c) for _, c in delta)
+                                           + acs.SIGNAL_TOKENS
+                                           ) * BYTES_PER_TOKEN
+                    req.future.set_result(ReadResult(
+                        tuple(self.store.get(name)), version,
+                        hit=not bool(decision.miss[req.agent]),
+                        latency_s=latency, delta=delta,
+                        delta_bytes=delta_bytes))
+            self.n_batches += 1
+
+        with span("broker.telemetry"):
+            if self.config.capture_trace:
+                self.trace.append_step(acts, arts, writes, decision.miss,
+                                       decision.version, latencies,
+                                       write_chunks=wmasks,
+                                       decide_s=busy_s,
+                                       batch_size=len(batch))
+            if tel is not None:
+                tel.record_batch(BatchObservation(
+                    names=self.names, acts=acts, arts=arts,
+                    writes=writes,
+                    miss=np.asarray(decision.miss, bool),
+                    version=np.asarray(decision.version, np.int64),
+                    ledger_delta=decision.ledger_delta,
+                    state_before=state_before,
+                    state_after=np.asarray(self.decider.arrays.state,
+                                           np.int32),
+                    ver_after=ver_after,
+                    wire_delta=decision.wire_delta,
+                    shard=self.shard, live=True, busy_s=busy_s,
+                    route=self.decider.backend, queue_depth=queue_depth,
+                    t_decide=t_decide, t_respond=now,
+                    t_submit=t_submit, latencies=latencies))
+            if self._on_commit is not None:
+                self._on_commit(self, {
+                    "acts": acts, "arts": arts, "writes": writes,
+                    "miss": decision.miss, "version": decision.version,
+                    "latencies": latencies, "write_chunks": wmasks,
+                    "busy_s": busy_s})
 
     # ------------------------------------------------------ invariants
     def _check_invariants(self, batch, ver_before, ver_after) -> None:

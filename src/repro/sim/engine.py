@@ -73,6 +73,8 @@ from repro.kernels.chunk_diff import (N_CHUNK_COUNTERS,
 from repro.kernels.mesi_transition import (N_COUNTERS, episode_step_keys,
                                            mesi_tick_pallas)
 from repro.launch.mesh import make_sweep_mesh
+from repro.obs import runtime as obs_runtime
+from repro.obs.spans import span
 from repro.sim.scenarios import ScenarioConfig
 
 # ---------------------------------------------------------------------------
@@ -275,7 +277,10 @@ def _shard_wrap(run_grid, plan: ShardPlan, n_cell_operands: int,
 
 
 def _call_grid(fn, *args) -> dict:
-    """Execute a compiled grid program and gather to host.
+    """Execute a compiled grid program and gather to host: the call
+    until it returns (``sweep.dispatch``: trace, lower, compile or load
+    on a cold shape, then dispatch) and the gather (``sweep.readback``:
+    device wait and transfer).
 
     The donated key operands rarely alias an output buffer on CPU
     (dtype/shape mismatch), and XLA warns about every unusable
@@ -286,7 +291,10 @@ def _call_grid(fn, *args) -> dict:
     with warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
-        return jax.device_get(fn(*args))
+        with span("sweep.dispatch"):
+            out = fn(*args)
+    with span("sweep.readback"):
+        return jax.device_get(out)
 
 
 # ---------------------------------------------------------------------------
@@ -689,7 +697,8 @@ def _grid_keys(seeds: Sequence[int], n_runs: int) -> jax.Array:
 def _grid_call(fn, plan: ShardPlan, n_runs: int, *cell_args) -> dict:
     """Run a grid program: append the (padded) global ``run_ids``
     operand, execute, and slice off any padded trailing runs."""
-    run_ids = jnp.arange(plan.pad_runs, dtype=jnp.int32)
+    with span("sweep.operands"):
+        run_ids = jnp.arange(plan.pad_runs, dtype=jnp.int32)
     out = _call_grid(fn, *cell_args, run_ids)
     if plan.pad_runs != n_runs:
         out = {k: a[..., :n_runs] for k, a in out.items()}
@@ -897,26 +906,33 @@ def compare_workloads(workloads, tick_backend: Optional[str] = None,
     for i, w in enumerate(workloads):
         groups.setdefault((_static_key(w.acs), w.n_runs), []).append(i)
     results: list = [None] * len(workloads)
-    for (_, n_runs), idxs in groups.items():
-        sub = [workloads[i] for i in idxs]
-        cfg = sub[0].acs
-        backend = tick_backend or resolve_tick_backend(
-            cfg, len(sub) * n_runs)
-        plan = shard_plan(len(sub), n_runs, devices)
-        fn = _het_grid_fn(cfg, include_broadcast=True,
-                          tick_backend=backend, plan=plan)
-        cell_ops = [_rate_stack(sub)]
-        if acs.content_enabled(cfg):
-            cell_ops.append(_locality_stack(sub))
-        out = _grid_call(fn, plan, n_runs, *cell_ops,
-                         _base_keys([w.seed for w in sub]))
-        for j, i in enumerate(idxs):
-            bc = _result_from(_cell(out, 0, j), sub[j].name,
-                              acs.STRATEGY_NAMES[acs.BROADCAST], n_runs)
-            co = _result_from(_cell(out, 1, j), sub[j].name,
-                              acs.STRATEGY_NAMES[cfg.strategy], n_runs)
-            results[i] = _comparison_of(
-                sub[j].name, sub[j].effective_volatility(), bc, co)
+    # one record per call: operands, dispatch, readback, results
+    with obs_runtime.sweep_call():
+        for (_, n_runs), idxs in groups.items():
+            sub = [workloads[i] for i in idxs]
+            cfg = sub[0].acs
+            backend = tick_backend or resolve_tick_backend(
+                cfg, len(sub) * n_runs)
+            plan = shard_plan(len(sub), n_runs, devices)
+            fn = _het_grid_fn(cfg, include_broadcast=True,
+                              tick_backend=backend, plan=plan)
+            with span("sweep.operands"):
+                cell_ops = [_rate_stack(sub)]
+                if acs.content_enabled(cfg):
+                    cell_ops.append(_locality_stack(sub))
+                cell_ops.append(_base_keys([w.seed for w in sub]))
+            out = _grid_call(fn, plan, n_runs, *cell_ops)
+            with span("sweep.results"):
+                for j, i in enumerate(idxs):
+                    bc = _result_from(
+                        _cell(out, 0, j), sub[j].name,
+                        acs.STRATEGY_NAMES[acs.BROADCAST], n_runs)
+                    co = _result_from(
+                        _cell(out, 1, j), sub[j].name,
+                        acs.STRATEGY_NAMES[cfg.strategy], n_runs)
+                    results[i] = _comparison_of(
+                        sub[j].name, sub[j].effective_volatility(), bc,
+                        co)
     return results
 
 

@@ -15,9 +15,14 @@ tests/README.md "Observability tier"):
   * span lifecycle under true concurrency - adversarial ping-pong
     clients produce request + decide spans whose Chrome-trace JSON
     round-trips with the documented schema;
+  * phase spans: one record per committed batch (one per sweep call),
+    whose phases tile the batch, whose build time is charged to the
+    batch that paid it, and which reach a profiler trace nested in the
+    benchmark's annotations; the record ring keeps every request of
+    the batches it holds;
   * the unified stats schema and its deprecation shim, trace schema
     v4 round-trips (v3 payloads load with defaults), the ``metrics``
-    TCP verb, and the jit/warmup compile log.
+    TCP verb, and the build-event log.
 
 Async tests run via ``asyncio.run`` inside plain pytest functions (no
 pytest-asyncio dependency).
@@ -33,7 +38,7 @@ import numpy as np
 import pytest
 
 from repro.obs import (MetricsConformanceError, MetricsRegistry,
-                       SpanRecorder, check_metrics_conformance)
+                       Telemetry, check_metrics_conformance)
 from repro.obs import runtime as obs_runtime
 from repro.obs import stats as obs_stats
 from repro.service import (BrokerConfig, CoherenceBroker, CoherenceConfig,
@@ -112,17 +117,48 @@ def test_snapshot_and_prometheus_round_trip():
         assert line.startswith("#") or len(line.rsplit(" ", 1)) == 2
 
 
+def _batches(config: BrokerConfig, rounds: int, per_round: int,
+             capacity: int = 1 << 14) -> CoherenceBroker:
+    """``rounds`` batches of ``per_round`` reads each, on a broker whose
+    span records hold ``capacity`` batches."""
+    async def main():
+        tel = Telemetry(config.n_agents, span_capacity=capacity)
+        async with CoherenceBroker(config, telemetry=tel) as broker:
+            for r in range(rounds):
+                await asyncio.gather(*(
+                    broker.read(a, f"artifact-{(a + r) % len(config.artifacts)}")
+                    for a in range(per_round)))
+            return broker
+    return asyncio.run(main())
+
+
 def test_span_recorder_bounded():
-    rec = SpanRecorder(capacity=4)
-    for i in range(10):
-        rec.add(f"s{i}", "request", ts_s=float(i), dur_s=0.1,
-                pid=0, tid=i)
-    assert rec.n_recorded == 10         # exact count survives eviction
+    broker = _batches(_config(n=6, m=2), rounds=10, per_round=3,
+                      capacity=4)
+    rec = broker.telemetry.spans
+    assert broker.n_batches == 10
+    # exact count survives eviction: 10 batch spans + 30 request spans
+    assert rec.n_recorded == 40
+    assert len(rec.records) == 4
     trace = rec.chrome_trace()
-    assert len(trace["traceEvents"]) == 4
+    cats = [e["cat"] for e in trace["traceEvents"]]
+    assert cats.count("batch") == 4 and cats.count("request") == 12
     ev = json.loads(rec.to_chrome_json())["traceEvents"][0]
     assert ev["ph"] == "X" and {"name", "cat", "ts", "dur", "pid",
                                 "tid"} <= set(ev)
+
+
+def test_request_views_count_every_request_past_the_span_count():
+    """The ring counts batches, not spans: 40 requests in 5 batches fit
+    a ring of 8, and every one of them is a request span."""
+    broker = _batches(_config(n=8, m=3), rounds=5, per_round=8,
+                      capacity=8)
+    rec = broker.telemetry.spans
+    reqs = [s for s in rec.spans if s.cat == "request"]
+    assert len(reqs) == 40 == broker.ledger.n_reads
+    assert rec.n_recorded == 45
+    assert all(s.args["queue_s"] >= 0.0 for s in reqs)
+    assert len({(s.tid, s.ts_s) for s in reqs}) == 40
 
 
 # ---------------------------------------------------------------------------
@@ -367,19 +403,127 @@ def test_launch_verify_metrics_smoke():
 
 
 # ---------------------------------------------------------------------------
-# Compile/warmup instrumentation.
+# Phase spans and build accounting.
+
+#: the broker's phases directly under broker.batch, and the decider's
+#: under broker.decide
+BATCH_PHASES = ("broker.cut", "broker.stage", "broker.decide",
+                "broker.checks", "broker.respond", "broker.telemetry")
+DECIDE_PHASES = ("broker.decide.stage", "broker.decide.call",
+                 "broker.decide.readback", "broker.decide.outcomes")
 
 
 def test_compile_log_records_fresh_trace():
-    before = obs_runtime.compile_count("scan")
+    before = obs_runtime.compile_count("broker.decide.call")
+    # a shape no other test uses -> guaranteed fresh jit trace
+    broker = _batches(_config(n=11, m=3, tokens=48), rounds=1,
+                      per_round=1)
+    assert obs_runtime.compile_count("broker.decide.call") >= before + 1
+    built = [e for e in obs_runtime.compile_events()
+             if e["route"] == "broker.decide.call"]
+    assert {"trace", "lower", "compile"} <= {e["kind"] for e in built}
+    assert all(e["dur_s"] > 0.0 for e in built)
+    assert broker.stats()["telemetry"]["compile_traces"] >= 1
+    compiles = [e for e in broker.telemetry.chrome_trace()["traceEvents"]
+                if e["cat"] == "compile"]
+    assert compiles and all(e["tid"] == "jit" for e in compiles)
+
+
+def test_phases_tile_the_batch():
+    """Scan route: the broker's phases cover its batch, and the
+    decider's its decide, to within 3 %."""
+    broker = _batches(_config(n=48, m=5), rounds=8, per_round=48)
+    records = list(broker.telemetry.spans.records)
+    assert len(records) == broker.n_batches == 8
+    steady = records[1:]                # the first batch compiles
+    for rec in steady:
+        assert set(BATCH_PHASES + DECIDE_PHASES) <= set(rec.phases)
+        for name in BATCH_PHASES:
+            assert rec.phases[name][3] == "broker.batch"
+        for name in DECIDE_PHASES:
+            assert rec.phases[name][3] == "broker.decide"
+        assert sum(rec.seconds(name) for name in BATCH_PHASES) \
+            <= rec.flush_s
+        assert rec.seconds("broker.decide") >= rec.decide_s
+        assert rec.self_seconds("broker.decide") >= 0.0
+    flush = sum(rec.flush_s for rec in steady)
+    decide = sum(rec.seconds("broker.decide") for rec in steady)
+    assert sum(rec.self_seconds("broker.batch") for rec in steady) \
+        <= 0.03 * flush
+    assert sum(rec.self_seconds("broker.decide") for rec in steady) \
+        <= 0.03 * decide
+
+
+def test_build_time_is_charged_to_the_batch_that_paid_it():
+    """Scan route: the first batch of a fresh shape traces, lowers and
+    compiles its decider; every later batch builds nothing."""
+    broker = _batches(_config(n=13, m=5, tokens=40), rounds=4,
+                      per_round=13)
+    first, *later = broker.telemetry.spans.records
+    assert first.trace_s > 0 and first.lower_s > 0 and first.compile_s > 0
+    assert first.n_builds >= 1
+    assert first.build_s <= first.seconds("broker.decide")
+    for rec in later:
+        assert rec.build_s == 0.0 and rec.n_builds == 0
+
+
+def test_phases_reach_the_profiler_trace(tmp_path):
+    """A CPU profiler trace holds the phase annotations nested in the
+    benchmark's ``broker.flush``, and the benchmark's gap labelling
+    names the innermost phase."""
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    from bench import tracing
+
+    config = _config(n=9, m=3, tokens=32)
 
     async def main():
-        # a shape no other test uses -> guaranteed fresh jit trace
-        cfg = _config(n=11, m=3, tokens=48)
-        async with CoherenceBroker(cfg) as broker:
-            await broker.read(0, "artifact-0")
+        async with CoherenceBroker(config) as broker:
+            flush = broker._flush_once
+
+            def annotated():
+                with TraceAnnotation("broker.flush"):
+                    flush()
+            broker._flush_once = annotated
+            await broker.read(0, "artifact-0")          # warm
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                for a in range(3):
+                    await broker.read(a, "artifact-1")
+            finally:
+                jax.profiler.stop_trace()
     asyncio.run(main())
-    assert obs_runtime.compile_count("scan") >= before + 1
-    warm = [e for e in obs_runtime.compile_events()
-            if e["kind"] == "warmup" and "agents=11" in e["label"]]
-    assert warm and warm[-1]["dur_s"] > 0.0
+    space = ProfileData.from_file(
+        str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    labels = [(ev.start_ns, ev.end_ns, ev.name)
+              for plane in space.planes if plane.name == tracing.HOST_PLANE
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith(tracing.LABEL_PREFIXES)]
+    flushes = [iv for iv in labels if iv[2] == "broker.flush"]
+    calls = [iv for iv in labels if iv[2] == "broker.decide.call"]
+    assert len(flushes) == 3 and len(calls) == 3
+    for s, e, _ in calls:
+        assert any(fs <= s and e <= fe for fs, fe, _ in flushes)
+        assert tracing._label((s + e) / 2, labels) == "broker.decide.call"
+    names = {iv[2] for iv in labels}
+    assert set(BATCH_PHASES + DECIDE_PHASES) | {"broker.batch"} <= names
+
+
+def test_sweep_call_leaves_one_record():
+    from repro.sim import compare_workloads
+    ws = [workloads.make("zipf", n_agents=4, n_artifacts=3,
+                         artifact_tokens=32, n_steps=3, n_runs=2,
+                         seed=s) for s in (5, 6)]
+    n0 = len(obs_runtime.sweep_records())
+    for _ in range(2):
+        compare_workloads(ws, tick_backend="scan")
+    records = obs_runtime.sweep_records()[-2:]
+    assert len(obs_runtime.sweep_records()) == n0 + 2
+    sweep = {"sweep.operands", "sweep.dispatch", "sweep.readback",
+             "sweep.results"}
+    for rec in records:
+        assert set(rec.phases) == sweep
+        assert all(cell[3] is None for cell in rec.phases.values())
+        assert sum(rec.seconds(name) for name in sweep) <= rec.wall_s
+    assert records[1].build_s <= records[0].build_s

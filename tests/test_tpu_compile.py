@@ -64,6 +64,11 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_ops(compiled) -> list:
+    return [line for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 #: (B sims, n agents, m artifacts, block): the served decision batch at
 #: the service benchmark's 32 agents (n+1 prefix sims), the oracle's one
 #: sim, the sweep route, and the decision batch at the kernel's
@@ -138,3 +143,31 @@ def test_sweep_program_compiles_for_v5e(topo, monkeypatch, devices):
             jax.ShapeDtypeStruct((V, 2), jnp.uint32, sharding=cells),
             jax.ShapeDtypeStruct((R,), jnp.int32, sharding=runs)]
     _assert_kernel(fn.lower(*args).compile())
+
+
+@pytest.mark.parametrize("kernel", ["mesi_tick", "chunk_tick"])
+def test_kernels_carry_their_names(one_chip, kernel):
+    """Each kernel's device operation is named after it, and still reads
+    as a Mosaic call with its output count, the mark the benchmark's
+    trace reduction finds it by."""
+    from bench.runners.open_loop import KERNELS
+    from bench.tracing import custom_call_outputs
+
+    if kernel == "mesi_tick":
+        B, n, m, block = MESI_SHAPES["fleet"]
+        shapes = ((B, n, m), (B, m), (B, n, m), (B, n, m), (B, n), (B, n),
+                  (B, n))
+        fn = lambda *a: mesi_tick_pallas(    # noqa: E731
+            *a, artifact_tokens=4096, block_sims=block, interpret=False)
+    else:
+        B, n, m, C, block = CHUNK_SHAPES["service"]
+        shapes = ((B, m, C), (B, n, m, C), (B, m, C), (B, n), (B, n),
+                  (B, n), (B, n, C))
+        fn = lambda *a: chunk_tick_pallas(   # noqa: E731
+            *a, artifact_tokens=4096, chunk_tokens=4096 // C,
+            block_sims=block, interpret=False)
+    compiled = jax.jit(fn).lower(
+        *[_i32(s, one_chip) for s in shapes]).compile()
+    (op,) = _kernel_ops(compiled)
+    assert op.lstrip().startswith(f"%{kernel}")
+    assert custom_call_outputs(op) == KERNELS[kernel]
