@@ -221,6 +221,32 @@ def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
     return st, ver[:, 0], sy, rd, cnt[:, 0], miss[:, 0]
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "artifact_tokens", "eager", "access_k", "signal_tokens", "interpret"))
+def mesi_decision_program(state, version, last_sync, reads_since_fetch,
+                          acts_b, arts, writes, *, artifact_tokens: int,
+                          eager: bool, access_k: int, signal_tokens: int,
+                          interpret: bool):
+    """The device work of :func:`mesi_decision_batch`, one program per
+    static config and shape: the single directory and ``arts``/``writes``
+    (n,) tiled to the ``B`` prefix sims of ``acts_b`` (B, n), one
+    ``mesi_tick_pallas`` call, and the full batch's row (the last sim).
+
+    Returns ``((state', version', sync', reads', counters (8,)),
+    counters (B, 8), sync (B, n, m))``: the full-batch transition, then
+    each sim's counters and sync cells for the per-request outcomes.
+    """
+    B = acts_b.shape[0]
+    tile = lambda arr: jnp.broadcast_to(arr, (B,) + arr.shape)
+    st, ver, sy, rd, cnt, _ = mesi_tick_pallas(
+        tile(state), tile(version), tile(last_sync),
+        tile(reads_since_fetch), acts_b, tile(arts), tile(writes),
+        artifact_tokens=artifact_tokens, eager=eager, access_k=access_k,
+        signal_tokens=signal_tokens, block_sims=DECISION_BLOCK,
+        interpret=interpret)
+    return (st[-1], ver[-1], sy[-1], rd[-1], cnt[-1]), cnt, sy
+
+
 def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
                         acts, arts, writes, *, artifact_tokens: int,
                         eager: bool = False, access_k: int = 0,
@@ -256,31 +282,25 @@ def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
         zc = jnp.zeros((N_COUNTERS,), jnp.int32)
         return (state, version, last_sync, reads_since_fetch, zc,
                 jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32))
-    # The served decide's phases are with-blocks in place (the kernel's
-    # source locations carry the whole stack: a frame more here costs
-    # lowering time on every batch).
+    # The served decide's phases are with-blocks in place (a frame more
+    # adds host time to every batch; the program itself is built once).
     with span("broker.decide.stage"):
         # sim j enables the first j requests; sim 0 is the no-op
         # baseline.  B is padded to the FIXED n+1 (rows past k repeat
         # the full batch, so their counter deltas are zero) - every
         # micro-batch size shares one compiled program instead of one
         # Mosaic compile per distinct k.
-        B = n + 1
-        acts_b = np.zeros((B, n), np.int32)
+        acts_b = np.zeros((n + 1, n), np.int32)
         for j, a in enumerate(order):
             acts_b[j + 1:, a] = 1
-        tile = lambda arr: jnp.broadcast_to(arr, (B,) + arr.shape)
-        args = (tile(state), tile(version), tile(last_sync),
-                tile(reads_since_fetch), jnp.asarray(acts_b),
-                tile(jnp.asarray(arts, jnp.int32)),
-                tile(jnp.asarray(writes, jnp.int32)))
+        arts_i = np.asarray(arts, np.int32)
+        writes_i = np.asarray(writes, np.int32)
     with span("broker.decide.call"):
-        st, ver, sy, rd, cnt, _ = mesi_tick_pallas(
-            *args, artifact_tokens=artifact_tokens, eager=eager,
+        full, cnt, sy = mesi_decision_program(
+            state, version, last_sync, reads_since_fetch, acts_b, arts_i,
+            writes_i, artifact_tokens=artifact_tokens, eager=eager,
             access_k=access_k, signal_tokens=signal_tokens,
-            block_sims=DECISION_BLOCK, interpret=interpret)
-        full = (st[-1], ver[-1], sy[-1], rd[-1], cnt[-1])
-        del args        # the n+1 replicas are released here
+            interpret=resolve_interpret(interpret))
     with span("broker.decide.readback"):
         cnt_np = np.asarray(cnt, np.int64)
         sync_np = np.asarray(sy, np.int64)

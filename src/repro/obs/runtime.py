@@ -15,10 +15,10 @@ lowering is part of it) and
     bounded event log with exact per-(kind, route) counts.
 
 This sees every route the same way: the scan decider's jit, the
-kernel route's eager ``pallas_call`` (re-traced, lowered and loaded on
-each batch), the sweep grid.  Telemetry snapshots read the log; the
-conformance leg excludes it (builds are process-global and
-timing-dependent by nature).
+kernel route's jitted decision programs, the sweep grid; each is built
+on its first call per static config and shape.  Telemetry snapshots
+read the log; the conformance leg excludes it (builds are
+process-global and timing-dependent by nature).
 """
 
 from __future__ import annotations

@@ -21,7 +21,9 @@ simulator (and therefore with the four-way differential oracle):
               own counters.  Covers the differential strategies
               (lazy / eager / access_count) with ``max_stale_steps=0``;
               staleness diagnostics are scan-route-only, mirroring the
-              oracle's Pallas scope note.
+              oracle's Pallas scope note.  Its device work, and the
+              content plane's chunk tick, are jitted programs built
+              once per static config and shape, as the scan pass is.
 
 ``auto`` resolves to the kernel route on a real TPU backend (where the
 sim engine also routes ticks through the kernel) and to ``scan``
@@ -39,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import acs
-from repro.kernels.backend import interpret_default
+from repro.kernels.backend import interpret_default, resolve_interpret
 from repro.kernels.chunk_diff import (chunk_tick_pallas, chunk_tick_ref,
                                       resolve_chunk_route)
 from repro.kernels.mesi_transition import mesi_decision_batch
@@ -106,6 +108,29 @@ def _scan_decider(cfg: acs.ACSConfig):
                                      writes)
 
     return jax.jit(fn)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "artifact_tokens", "chunk_tokens", "signal_tokens", "interpret"))
+def _chunk_decider(chunk_version, chunk_sync, chunk_dirty, miss,
+                   write_acts, arts, write_chunks, *, artifact_tokens,
+                   chunk_tokens, signal_tokens, interpret):
+    """The content plane's chunk tick on the single directory (one sim),
+    compiled once per static config and shape like ``_scan_decider``.
+    Returns ``chunk_tick_pallas``'s outputs without the sim axis."""
+    out = chunk_tick_pallas(
+        chunk_version[None], chunk_sync[None], chunk_dirty[None],
+        miss[None], write_acts[None], arts[None], write_chunks[None],
+        artifact_tokens=artifact_tokens, chunk_tokens=chunk_tokens,
+        signal_tokens=signal_tokens, interpret=interpret)
+    return tuple(o[0] for o in out)
+
+
+def _chunk_decider_ref(*args, **static):
+    """``chunk_tick_ref`` in ``_chunk_decider``'s place
+    (``REPRO_CHUNK_DIFF=scan``)."""
+    return tuple(o[0] for o in chunk_tick_ref(*(x[None] for x in args),
+                                              **static))
 
 
 #: ACSMetrics counter fields forwarded into the broker's token ledger.
@@ -252,30 +277,28 @@ class BatchDecider:
             # measured dirty masks.  REPRO_CHUNK_DIFF=scan forces the
             # pure-jnp reference (bit-identical; oracle-checked).
             with span("broker.decide.stage"):
-                tick = (chunk_tick_ref
+                tick = (_chunk_decider_ref
                         if resolve_chunk_route("pallas") == "scan"
-                        else chunk_tick_pallas)
+                        else _chunk_decider)
                 wact = (acts_np & writes_np).astype(np.int32)
                 chunk_args = (
-                    self.arrays.chunk_version[None],
-                    self.arrays.chunk_sync[None],
-                    self.arrays.chunk_dirty[None],
-                    np.asarray(miss, np.int32)[None], wact[None],
-                    np.asarray(arts, np.int32)[None],
-                    np.asarray(write_chunks, np.int32)[None])
+                    self.arrays.chunk_version, self.arrays.chunk_sync,
+                    self.arrays.chunk_dirty, np.asarray(miss, np.int32),
+                    wact, np.asarray(arts, np.int32),
+                    np.asarray(write_chunks, np.int32))
             with span("broker.decide.call"):
-                cv, cs, dirty, fetched_b, ccnt = tick(
+                cv, cs, dirty, fetched_c, ccnt = tick(
                     *chunk_args,
                     artifact_tokens=self.cfg.artifact_tokens,
                     chunk_tokens=self.cfg.chunk_tokens,
-                    signal_tokens=acs.SIGNAL_TOKENS)
+                    signal_tokens=acs.SIGNAL_TOKENS,
+                    interpret=resolve_interpret(None))
             with span("broker.decide.readback"):
-                ccnt_np = np.asarray(ccnt[0], np.int64)
-                fetched = np.asarray(fetched_b[0], bool)
+                ccnt_np = np.asarray(ccnt, np.int64)
+                fetched = np.asarray(fetched_c, bool)
             with span("broker.decide.outcomes"):
                 self.arrays = self.arrays._replace(
-                    chunk_version=cv[0], chunk_sync=cs[0],
-                    chunk_dirty=dirty[0])
+                    chunk_version=cv, chunk_sync=cs, chunk_dirty=dirty)
                 wire = {"delta_bytes": int(ccnt_np[0]),
                         "full_bytes": int(ccnt_np[1]),
                         "n_chunks_fetched": int(ccnt_np[2])}

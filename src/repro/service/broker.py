@@ -416,8 +416,8 @@ class CoherenceBroker:
 
     def _flush_once(self) -> None:
         # one batch, from cut to the end of its telemetry; the phase
-        # spans are with-blocks in place (a frame more on the way to the
-        # kernel costs its lowering time)
+        # spans are with-blocks in place (a frame more adds host time to
+        # every batch)
         tel = self.telemetry
         with (tel.spans.batch(self.shard) if tel is not None
               else span(BATCH)):

@@ -467,6 +467,39 @@ def test_build_time_is_charged_to_the_batch_that_paid_it():
         assert rec.build_s == 0.0 and rec.n_builds == 0
 
 
+@pytest.mark.pallas
+@pytest.mark.parametrize("chunk_tokens", [0, 20], ids=["plain", "content"])
+def test_kernel_route_builds_once(monkeypatch, chunk_tokens):
+    """Kernel route (interpret mode here): the first batch of a fresh
+    shape builds the decision programs; every later batch, reads and
+    writes alike, dispatches them from the jit cache."""
+    monkeypatch.setenv("REPRO_SERVICE_DECIDE", "pallas")
+    config = _config(n=10, m=5, tokens=80, chunk_tokens=chunk_tokens)
+    calls = []
+
+    async def main():
+        tel = Telemetry(config.n_agents)
+        async with CoherenceBroker(config, telemetry=tel) as broker:
+            for r in range(4):
+                await asyncio.gather(*(
+                    broker.write(a, f"artifact-{(a + r) % 5}",
+                                 [r * 100 + a] * 80)
+                    if (a + r) % 3 == 0 else
+                    broker.read(a, f"artifact-{(a + r) % 5}")
+                    for a in range(10)))
+                calls.append(obs_runtime.compile_count("broker.decide.call"))
+            return broker
+
+    broker = asyncio.run(main())
+    assert broker.decider.backend == "pallas"
+    first, *later = broker.telemetry.spans.records
+    assert len(later) == 3
+    assert first.n_builds >= 1
+    for rec in later:
+        assert rec.build_s == 0.0 and rec.n_builds == 0
+    assert calls[1:] == calls[:1] * 3
+
+
 def test_phases_reach_the_profiler_trace(tmp_path):
     """A CPU profiler trace holds the phase annotations nested in the
     benchmark's ``broker.flush``, and the benchmark's gap labelling

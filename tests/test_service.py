@@ -233,6 +233,53 @@ def test_pallas_backend_matches_scan():
                (s2.agents, s2.arts, s2.writes, s2.miss, s2.version)
 
 
+#: directory fields both decision routes maintain (``last_validate`` and
+#: ``agent_actions`` are scan-route staleness diagnostics)
+_ROUTE_FIELDS = ("state", "version", "last_sync", "reads_since_fetch",
+                 "chunk_version", "chunk_sync", "chunk_dirty")
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("strategy,chunk_tokens", [
+    ("lazy", 0), ("eager", 0), ("access_count", 0), ("lazy", 16)])
+def test_kernel_route_matches_scan_batch_for_batch(strategy, chunk_tokens):
+    """One seeded batch sequence through both deciders: every request's
+    miss and served version, every batch's ledger (and wire) deltas and
+    fetched chunks, and the final directory agree bit for bit."""
+    from repro.service.batching import (_LEDGER_FIELDS, _WIRE_FIELDS,
+                                        BatchDecider)
+
+    n, m = 9, 4
+    cfg = _config(n=n, m=m, tokens=64, strategy=strategy, access_k=2,
+                  chunk_tokens=chunk_tokens).acs_config()
+    scan = BatchDecider(cfg, backend="scan")
+    kernel = BatchDecider(cfg, backend="pallas")
+    assert (scan.backend, kernel.backend) == ("scan", "pallas")
+    rng = np.random.default_rng(2026)
+    for _ in range(12):
+        acts = rng.random(n) < 0.7
+        arts = rng.integers(0, m, n).astype(np.int32)
+        writes = rng.random(n) < 0.3
+        chunks = ((rng.random((n, 64 // chunk_tokens)) < 0.4)
+                  & (acts & writes)[:, None] if chunk_tokens else None)
+        a = scan.decide(acts, arts, writes, chunks)
+        b = kernel.decide(acts, arts, writes, chunks)
+        assert np.array_equal(a.miss[acts], b.miss[acts])
+        assert np.array_equal(a.version[acts], b.version[acts])
+        assert a.ledger_delta == b.ledger_delta
+        assert a.wire_delta == b.wire_delta
+        if chunk_tokens:
+            assert np.array_equal(a.fetched_chunks[acts],
+                                  b.fetched_chunks[acts])
+    for f in _ROUTE_FIELDS:
+        x, y = getattr(scan.arrays, f), getattr(kernel.arrays, f)
+        assert (x is None) == (y is None), f
+        assert x is None or np.array_equal(np.asarray(x), np.asarray(y)), f
+    fields = _LEDGER_FIELDS + (_WIRE_FIELDS if chunk_tokens else ())
+    assert ([int(getattr(scan.metrics, f)) for f in fields]
+            == [int(getattr(kernel.metrics, f)) for f in fields])
+
+
 def test_backend_resolution_guards():
     cfg = _config(max_stale_steps=2).acs_config()
     assert resolve_decide_backend(cfg, "auto") == "scan"

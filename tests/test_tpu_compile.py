@@ -171,3 +171,33 @@ def test_kernels_carry_their_names(one_chip, kernel):
     (op,) = _kernel_ops(compiled)
     assert op.lstrip().startswith(f"%{kernel}")
     assert custom_call_outputs(op) == KERNELS[kernel]
+
+
+@pytest.mark.parametrize("program", ["mesi_service", "mesi_fleet",
+                                     "chunk_service"])
+def test_served_programs_compile_for_v5e(one_chip, program):
+    """The served path's jitted decision programs (the n+1 prefix tile,
+    the kernel and the full-batch row; the content plane's one-sim chunk
+    tick) each hold exactly one named kernel call on a v5e chip."""
+    from bench.runners.open_loop import KERNELS
+    from bench.tracing import custom_call_outputs
+    from repro.kernels.mesi_transition import mesi_decision_program
+    from repro.service.batching import _chunk_decider
+
+    if program.startswith("mesi"):
+        kernel = "mesi_tick"
+        B, n, m, _ = MESI_SHAPES[program.split("_")[1]]
+        shapes = ((n, m), (m,), (n, m), (n, m), (B, n), (n,), (n,))
+        lowered = mesi_decision_program.lower(
+            *[_i32(s, one_chip) for s in shapes], artifact_tokens=4096,
+            eager=False, access_k=0, signal_tokens=12, interpret=False)
+    else:
+        kernel = "chunk_tick"
+        _, n, m, C, _ = CHUNK_SHAPES["service"]
+        shapes = ((m, C), (n, m, C), (m, C), (n,), (n,), (n,), (n, C))
+        lowered = _chunk_decider.lower(
+            *[_i32(s, one_chip) for s in shapes], artifact_tokens=4096,
+            chunk_tokens=4096 // C, signal_tokens=12, interpret=False)
+    (op,) = _kernel_ops(lowered.compile())
+    assert op.lstrip().startswith(f"%{kernel}")
+    assert custom_call_outputs(op) == KERNELS[kernel]
