@@ -23,6 +23,7 @@ Counters layout (out[..., c]): 0 fetch_tokens, 1 signal_tokens,
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +37,7 @@ from repro.obs.spans import span
 _I, _S = int(MESIState.I), int(MESIState.S)
 N_COUNTERS = 8
 
-#: sims per grid step of ``mesi_decision_batch``: its n+1 prefix sims
+#: sims per grid step of ``mesi_decision_dispatch``: its n+1 prefix sims
 #: tile over a grid so that a 256-agent directory block stays within VMEM.
 DECISION_BLOCK = 8
 
@@ -227,7 +228,7 @@ def mesi_decision_program(state, version, last_sync, reads_since_fetch,
                           acts_b, arts, writes, *, artifact_tokens: int,
                           eager: bool, access_k: int, signal_tokens: int,
                           interpret: bool):
-    """The device work of :func:`mesi_decision_batch`, one program per
+    """The device work of :func:`mesi_decision_dispatch`, one program per
     static config and shape: the single directory and ``arts``/``writes``
     (n,) tiled to the ``B`` prefix sims of ``acts_b`` (B, n), one
     ``mesi_tick_pallas`` call, and the full batch's row (the last sim).
@@ -247,13 +248,27 @@ def mesi_decision_program(state, version, last_sync, reads_since_fetch,
     return (st[-1], ver[-1], sy[-1], rd[-1], cnt[-1]), cnt, sy
 
 
-def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
-                        acts, arts, writes, *, artifact_tokens: int,
-                        eager: bool = False, access_k: int = 0,
-                        signal_tokens: int = 12,
-                        interpret: bool | None = None):
-    """One micro-batch of live coherence decisions via prefix-replicated
-    simulations (the ``repro.service.batching`` kernel route).
+class DecisionInFlight(NamedTuple):
+    """A decision batch :func:`mesi_decision_dispatch` sent to the device
+    and :func:`mesi_decision_resolve` has not read back yet.
+    ``cnt``/``sy`` are ``None`` for an empty batch."""
+
+    order: np.ndarray    # acting agents, ascending
+    arts: np.ndarray     # (n,) artifact per agent slot
+    full: tuple          # the full-batch transition, on the device
+    cnt: object          # (B, 8) counters of the prefix sims
+    sy: object           # (B, n, m) sync cells of the prefix sims
+
+
+def mesi_decision_dispatch(state, version, last_sync, reads_since_fetch,
+                           acts, arts, writes, *, artifact_tokens: int,
+                           eager: bool = False, access_k: int = 0,
+                           signal_tokens: int = 12,
+                           interpret: bool | None = None
+                           ) -> DecisionInFlight:
+    """Dispatch one micro-batch of live coherence decisions via
+    prefix-replicated simulations (the ``repro.service.batching`` kernel
+    route); :func:`mesi_decision_resolve` reads the answers back.
 
     The kernel emits per-*simulation* aggregate counters, not
     per-request outcomes, yet a live broker must answer each request
@@ -270,9 +285,10 @@ def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
     Inputs: single-directory arrays - ``state``/``last_sync``/``reads``
     (n, m) int32, ``version`` (m,) int32 - plus the request vectors
     ``acts``/``arts``/``writes`` (n,) (at most one request per agent).
-    Returns ``(state', version', sync', reads', counters (8,),
-    miss (n,) bool, served_version (n,) int32)`` where the primed
-    arrays/counters are the full-batch transition.
+    Stages the prefix sims and dispatches the program, up to the end of
+    ``broker.decide.call``: its outputs stay on the device, unread, so
+    a caller may dispatch other devices' batches before it resolves
+    this one.
     """
     n, m = state.shape
     acts_np = np.asarray(acts, bool)
@@ -280,8 +296,9 @@ def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
     k = int(order.size)
     if k == 0:
         zc = jnp.zeros((N_COUNTERS,), jnp.int32)
-        return (state, version, last_sync, reads_since_fetch, zc,
-                jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32))
+        return DecisionInFlight(order, np.asarray(arts, np.int32),
+                                (state, version, last_sync,
+                                 reads_since_fetch, zc), None, None)
     # The served decide's phases are with-blocks in place (a frame more
     # adds host time to every batch; the program itself is built once).
     with span("broker.decide.stage"):
@@ -301,14 +318,27 @@ def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
             writes_i, artifact_tokens=artifact_tokens, eager=eager,
             access_k=access_k, signal_tokens=signal_tokens,
             interpret=resolve_interpret(interpret))
+    return DecisionInFlight(order, arts_i, full, cnt, sy)
+
+
+def mesi_decision_resolve(flight: DecisionInFlight) -> tuple:
+    """Read a dispatched batch's prefix sims back (waiting out the
+    program) and derive each request's outcome.  Returns ``(state',
+    version', sync', reads', counters (8,), miss (n,) bool,
+    served_version (n,) int32)`` where the primed arrays/counters are
+    the full-batch transition."""
+    n = flight.arts.shape[0]
+    if flight.cnt is None:
+        return flight.full + (jnp.zeros((n,), bool),
+                              jnp.zeros((n,), jnp.int32))
     with span("broker.decide.readback"):
-        cnt_np = np.asarray(cnt, np.int64)
-        sync_np = np.asarray(sy, np.int64)
+        cnt_np = np.asarray(flight.cnt, np.int64)
+        sync_np = np.asarray(flight.sy, np.int64)
     with span("broker.decide.outcomes"):
-        arts_np = np.asarray(arts, np.int64)
+        arts_np = np.asarray(flight.arts, np.int64)
         miss = np.zeros((n,), bool)
         served = np.zeros((n,), np.int32)
-        for j, a in enumerate(order):
+        for j, a in enumerate(flight.order):
             # counter slot 3 = n_fetches; the delta between prefix j+1
             # and prefix j is exactly request j's fill.
             miss[a] = (cnt_np[j + 1, 3] - cnt_np[j, 3]) == 1
@@ -317,4 +347,4 @@ def mesi_decision_batch(state, version, last_sync, reads_since_fetch,
             # (later eager pushes in the full batch must not leak into
             # this answer).
             served[a] = sync_np[j + 1, a, arts_np[a]]
-        return full + (jnp.asarray(miss), jnp.asarray(served))
+        return flight.full + (jnp.asarray(miss), jnp.asarray(served))

@@ -42,7 +42,7 @@ def shard_devices(n_shards: int, axis_name: str = "shards") -> tuple:
 
     Reuses the sweep-mesh machinery: a 1-D mesh over min(K, local
     devices) and a length-K tuple assigning each shard its device, so
-    every shard's micro-batch decision (``mesi_decision_batch`` /
+    every shard's micro-batch decision (``mesi_decision_dispatch`` /
     ``apply_actions``) runs as its own device program.  On a
     single-device host every shard maps to device 0 - byte-for-byte
     the unpinned behavior (CI forces 8 host devices to exercise the

@@ -5,13 +5,16 @@ A *span* times one phase of the program: ``with span("broker.decide"):``
 at a layer boundary.  Every span is mirrored as a
 ``jax.profiler.TraceAnnotation`` of the same name, so under a profiler
 it lands on the host plane of the device trace, on the device's clock.
-The unit of record is the committed micro-batch (``BatchRecord``, one
-per ``broker.batch``) or one sweep call (``Record``, one per
+The unit of record is the committed micro-batch (``BatchRecord``, whose
+root span is ``broker.batch``) or one sweep call (``Record``, one per
 ``compare_workloads``): each span closed while a record is open on the
 thread adds its seconds to the record's phase of that name (a phase
 that runs twice in one batch sums), with its first start, last end and
-parent.  Build time (trace, lower, compile or cache load) is charged
-to the open record by ``repro.obs.runtime``'s listener.
+parent.  The sharded plane runs each batch in two ``broker.batch``
+blocks, dispatch and resolve, with other shards' work between them;
+both go on the batch's one record (``SpanRecorder.resume``).  Build
+time (trace, lower, compile or cache load) is charged to the open
+record by ``repro.obs.runtime``'s listener.
 
 Spans are ``with`` blocks inside the functions they time, never
 wrappers: the Pallas kernels' source locations carry the whole Python
@@ -167,7 +170,9 @@ class recording(span):
     """``with recording(record, sink, name):`` opens ``record`` on the
     thread for the block; on a clean exit the record, with its
     ``t0``/``wall_s``, goes to ``sink``.  With a ``name`` the block is
-    also the record's root span."""
+    also the record's root span.  A record opened again (its work run
+    in two blocks) keeps the ``t0`` of its first block, and ``wall_s``
+    runs to the end of its last."""
 
     __slots__ = ("record", "_sink", "_prev")
 
@@ -193,8 +198,9 @@ class recording(span):
         rec = self.record
         _THREAD.record = self._prev
         if rec is not None:
-            rec.t0 = self._t0
-            rec.wall_s = time.perf_counter() - self._t0
+            if not rec.wall_s:      # a resumed record keeps its start
+                rec.t0 = self._t0
+            rec.wall_s = time.perf_counter() - rec.t0
             if exc_type is None and self._sink is not None:
                 self._sink(rec)
         return False
@@ -234,6 +240,12 @@ class SpanRecorder:
         """The root span of one micro-batch; the batch is kept once its
         telemetry has filled in its requests."""
         return recording(BatchRecord(shard), self._commit, BATCH)
+
+    def resume(self, rec: BatchRecord) -> recording:
+        """The rest of a batch whose first half ran in another
+        :meth:`batch` block: the phases of both halves go on its one
+        record, which is kept once this block closes."""
+        return recording(rec, self._commit, BATCH)
 
     def _commit(self, rec: BatchRecord) -> None:
         if rec.t_submit is None:        # nothing committed (empty cut

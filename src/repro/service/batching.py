@@ -15,7 +15,7 @@ simulator (and therefore with the four-way differential oracle):
               static broker config (module-level jit cache, same
               pattern as ``repro.sim.engine``).  Covers every
               invalidation strategy plus K-staleness enforcement.
-  ``pallas``  one ``kernels.mesi_transition.mesi_decision_batch`` call:
+  ``pallas``  one ``kernels.mesi_transition.mesi_decision_dispatch`` pass:
               the batched MESI transition kernel over prefix-replicated
               sims, which yields per-request outcomes from the kernel's
               own counters.  Covers the differential strategies
@@ -28,6 +28,12 @@ simulator (and therefore with the four-way differential oracle):
 ``auto`` resolves to the kernel route on a real TPU backend (where the
 sim engine also routes ticks through the kernel) and to ``scan``
 elsewhere; ``REPRO_SERVICE_DECIDE`` forces either.
+
+A decision is two halves on either route: ``dispatch`` stages the batch
+and calls the device program, returning its outputs unread, and
+``resolve`` reads them back and derives the outcomes.  ``decide`` runs
+the two back to back; the sharded plane dispatches every shard's batch
+before it resolves any, so the shards' programs run at once.
 """
 
 from __future__ import annotations
@@ -44,7 +50,8 @@ from repro.core import acs
 from repro.kernels.backend import interpret_default, resolve_interpret
 from repro.kernels.chunk_diff import (chunk_tick_pallas, chunk_tick_ref,
                                       resolve_chunk_route)
-from repro.kernels.mesi_transition import mesi_decision_batch
+from repro.kernels.mesi_transition import (mesi_decision_dispatch,
+                                          mesi_decision_resolve)
 from repro.obs.spans import span
 
 #: strategies the kernel route supports (== oracle DIFFERENTIAL scope).
@@ -64,6 +71,19 @@ class BatchDecision(NamedTuple):
     fetched_chunks: np.ndarray | None = None
     #: exact byte-ledger deltas (content plane; else None)
     wire_delta: dict | None = None
+
+
+class InFlight(NamedTuple):
+    """A micro-batch :meth:`BatchDecider.dispatch` sent to the device
+    and :meth:`BatchDecider.resolve` has not read back yet."""
+
+    acts: np.ndarray
+    arts: np.ndarray
+    writes: np.ndarray
+    write_chunks: np.ndarray | None
+    #: the route's unread outputs: the scan pass's ``(counters before,
+    #: outputs)``, the kernel route's ``DecisionInFlight``
+    device: object
 
 
 def _kernel_supported(cfg: acs.ACSConfig) -> bool:
@@ -149,9 +169,10 @@ class BatchDecider:
     one coalesced micro-batch per call.
 
     The broker is the *single writer* of this state - only the flush
-    task calls :meth:`decide`, which is what makes SWMR hold under true
-    asyncio interleaving (enforced with a reentrancy guard, checked by
-    the invariant suite after every batch).
+    task calls :meth:`decide` (or its halves), which is what makes SWMR
+    hold under true asyncio interleaving (enforced with a reentrancy
+    guard that holds from a dispatch to its resolve, checked by the
+    invariant suite after every batch).
     """
 
     def __init__(self, cfg: acs.ACSConfig, backend: str = "auto",
@@ -181,6 +202,15 @@ class BatchDecider:
         ``write_chunks`` (n, C) bool is required for chunked configs:
         the *measured* dirty chunk mask of each write in the batch
         (the broker diffs actual content digests)."""
+        return self.resolve(self.dispatch(acts, arts, writes,
+                                          write_chunks))
+
+    def dispatch(self, acts: np.ndarray, arts: np.ndarray,
+                 writes: np.ndarray,
+                 write_chunks: np.ndarray | None = None) -> InFlight:
+        """The first half of :meth:`decide`: stage the batch and call
+        the device program.  Its outputs stay on the device until
+        :meth:`resolve`; no other batch may be dispatched before."""
         if self._deciding:
             raise RuntimeError(
                 "re-entrant decide(): the broker's single-writer "
@@ -189,10 +219,21 @@ class BatchDecider:
             raise ValueError("chunked decider needs write_chunks masks")
         self._deciding = True
         try:
+            route = (self._dispatch_scan if self.backend == "scan"
+                     else self._dispatch_pallas)
+            return InFlight(acts, arts, writes, write_chunks,
+                            route(acts, arts, writes, write_chunks))
+        except BaseException:
+            self._deciding = False
+            raise
+
+    def resolve(self, flight: InFlight) -> BatchDecision:
+        """The second half of :meth:`decide`: read the dispatched
+        batch's outputs back and derive its outcomes."""
+        try:
             if self.backend == "scan":
-                return self._decide_scan(acts, arts, writes,
-                                         write_chunks)
-            return self._decide_pallas(acts, arts, writes, write_chunks)
+                return self._resolve_scan(flight)
+            return self._resolve_pallas(flight)
         finally:
             self._deciding = False
 
@@ -202,8 +243,7 @@ class BatchDecider:
     # trace, lower, compile or load, dispatch), readback (outputs to the
     # host: device wait and transfer) and outcomes (host derivation of
     # per-request outcomes and ledger deltas).
-    def _decide_scan(self, acts, arts, writes,
-                     write_chunks) -> BatchDecision:
+    def _dispatch_scan(self, acts, arts, writes, write_chunks):
         content = acs.content_enabled(self.cfg)
         fields = _LEDGER_FIELDS + (_WIRE_FIELDS if content else ())
         with span("broker.decide.readback"):
@@ -218,6 +258,12 @@ class BatchDecider:
             self.arrays, self.metrics, out = self._scan(
                 self.arrays, self.metrics, *batch)
             del batch
+        return before, out
+
+    def _resolve_scan(self, flight: InFlight) -> BatchDecision:
+        content = acs.content_enabled(self.cfg)
+        fields = _LEDGER_FIELDS + (_WIRE_FIELDS if content else ())
+        before, out = flight.device
         with span("broker.decide.readback"):
             after = {f: int(getattr(self.metrics, f)) for f in fields}
             miss = np.asarray(out.miss, bool)
@@ -233,12 +279,10 @@ class BatchDecider:
                                  ledger_delta=delta,
                                  fetched_chunks=fetched, wire_delta=wire)
 
-    def _decide_pallas(self, acts, arts, writes,
-                       write_chunks) -> BatchDecision:
+    def _dispatch_pallas(self, acts, arts, writes, write_chunks):
         a = self.arrays
-        # mesi_decision_batch times its own stage, call, readback and
-        # outcomes
-        st, ver, sy, rd, cnt, miss, served = mesi_decision_batch(
+        # mesi_decision_dispatch times its own stage and call
+        return mesi_decision_dispatch(
             a.state, a.version, a.last_sync, a.reads_since_fetch,
             np.asarray(acts, bool), np.asarray(arts, np.int32),
             np.asarray(writes, bool),
@@ -247,6 +291,13 @@ class BatchDecider:
             access_k=(self.cfg.access_k
                       if self.cfg.strategy == acs.ACCESS_COUNT else 0),
             signal_tokens=acs.SIGNAL_TOKENS)
+
+    def _resolve_pallas(self, flight: InFlight) -> BatchDecision:
+        acts, arts, writes, write_chunks = flight[:4]
+        a = self.arrays
+        # mesi_decision_resolve times its own readback and outcomes
+        st, ver, sy, rd, cnt, miss, served = mesi_decision_resolve(
+            flight.device)
         acts_np = np.asarray(acts, bool)
         writes_np = np.asarray(writes, bool)
         with span("broker.decide.readback"):

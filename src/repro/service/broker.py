@@ -225,6 +225,12 @@ class WriteResult(NamedTuple):
     dirty_chunks: tuple | None = None
 
 
+def _fail(batch: list, error: Exception) -> None:
+    for req in batch:
+        if not req.future.done():
+            req.future.set_exception(error)
+
+
 @dataclasses.dataclass
 class _Request:
     agent: int
@@ -233,6 +239,31 @@ class _Request:
     content: Optional[tuple]
     future: asyncio.Future
     t_submit: float
+
+
+class _Staged(NamedTuple):
+    """A cut batch as the decider takes it, with what its checks and
+    telemetry need from before the decision."""
+
+    batch: list
+    acts: np.ndarray
+    arts: np.ndarray
+    writes: np.ndarray
+    t_submit: np.ndarray
+    wmasks: Optional[np.ndarray]
+    state_before: Optional[np.ndarray]
+    queue_depth: int
+    ver_before: np.ndarray
+
+
+class _Flight(NamedTuple):
+    """A batch between its ``_flush_begin`` and its ``_flush_end``."""
+
+    staged: _Staged
+    decision: object          # the decider's ``InFlight``
+    t_decide: float
+    dispatch_s: float
+    record: object            # its ``BatchRecord`` (None: no telemetry)
 
 
 class CoherenceBroker:
@@ -248,7 +279,8 @@ class CoherenceBroker:
                  contents: Optional[Dict[str, Sequence[int]]] = None,
                  *, on_commit: Optional[Callable] = None,
                  device=None, telemetry: Optional[Telemetry] = None,
-                 shard: int = 0) -> None:
+                 shard: int = 0,
+                 wake: Optional[asyncio.Event] = None) -> None:
         if hasattr(config, "broker_view"):   # layered CoherenceConfig
             if not config.topology.trivial:
                 raise ValueError(
@@ -308,13 +340,19 @@ class CoherenceBroker:
         self.latencies = collections.deque(maxlen=config.latency_window)
         self.n_batches = 0
         self._pending: list = []
-        self._wake = asyncio.Event()
+        #: set by every submit.  A sharded plane passes its own
+        #: event: its one task flushes every shard, and this broker
+        #: starts no flush task of its own.
+        self._driven = wake is not None
+        self._wake = wake if wake is not None else asyncio.Event()
         self._flusher_task: Optional[asyncio.Task] = None
+        self._started = False
         self._closed = False
 
     # ------------------------------------------------------- lifecycle
     async def start(self) -> "CoherenceBroker":
-        if self._flusher_task is None:
+        self._started = True
+        if self._flusher_task is None and not self._driven:
             self._flusher_task = asyncio.get_running_loop().create_task(
                 self._flusher())
         return self
@@ -367,7 +405,7 @@ class CoherenceBroker:
         if not 0 <= agent < self.config.n_agents:
             raise ValueError(f"agent {agent} outside [0, "
                              f"{self.config.n_agents})")
-        if self._flusher_task is None:
+        if not self._started:
             raise RuntimeError("broker not started - use "
                                "`async with CoherenceBroker(...)` or "
                                "await broker.start()")
@@ -426,11 +464,60 @@ class CoherenceBroker:
             if not batch:
                 return
             try:
-                self._decide_and_resolve(batch)
+                staged = self._stage(batch)
+                with span("broker.decide"):
+                    t_decide = time.perf_counter()
+                    decision = self.decider.decide(
+                        staged.acts, staged.arts, staged.writes,
+                        write_chunks=staged.wmasks)
+                    busy_s = time.perf_counter() - t_decide
+                self._finish(staged, decision, t_decide, busy_s)
             except Exception as e:   # noqa: BLE001 - fail the batch,
-                for req in batch:    # not the event loop
-                    if not req.future.done():
-                        req.future.set_exception(e)
+                _fail(batch, e)      # not the event loop
+
+    # A flush task that holds several shards' batches in flight at once
+    # (``ShardedCoherenceBroker``) runs a flush in two halves: every
+    # shard's ``_flush_begin`` (cut, stage, dispatch), then each
+    # shard's ``_flush_end`` (resolve, checks, respond, telemetry).  The
+    # batch keeps one record over both halves.
+    def _flush_begin(self) -> Optional[_Flight]:
+        tel = self.telemetry
+        with (tel.spans.batch(self.shard) if tel is not None
+              else span(BATCH)) as record:
+            with span("broker.cut"):
+                batch = self._cut_batch()
+            if not batch:
+                return None
+            try:
+                staged = self._stage(batch)
+                with span("broker.decide"):
+                    t_decide = time.perf_counter()
+                    flight = self.decider.dispatch(
+                        staged.acts, staged.arts, staged.writes,
+                        write_chunks=staged.wmasks)
+                    dispatch_s = time.perf_counter() - t_decide
+            except Exception as e:   # noqa: BLE001
+                _fail(batch, e)
+                return None
+        return _Flight(staged, flight, t_decide, dispatch_s,
+                       record if tel is not None else None)
+
+    def _flush_end(self, flight: _Flight) -> None:
+        tel = self.telemetry
+        with (tel.spans.resume(flight.record) if tel is not None
+              else span(BATCH)):
+            try:
+                with span("broker.decide"):
+                    t_resolve = time.perf_counter()
+                    decision = self.decider.resolve(flight.decision)
+                    # the batch's own decide time: both halves, not the
+                    # other shards' work between them
+                    busy_s = (flight.dispatch_s + time.perf_counter()
+                              - t_resolve)
+                self._finish(flight.staged, decision, flight.t_decide,
+                             busy_s)
+            except Exception as e:   # noqa: BLE001
+                _fail(flight.staged.batch, e)
 
     def _measure_write_masks(self, batch: list) -> Optional[np.ndarray]:
         """(n, C) measured dirty chunk masks for the batch's writes.
@@ -460,9 +547,8 @@ class CoherenceBroker:
             pending[name] = new
         return masks
 
-    def _decide_and_resolve(self, batch: list) -> None:
+    def _stage(self, batch: list) -> _Staged:
         n = self.config.n_agents
-        tel = self.telemetry
         with span("broker.stage"):
             acts = np.zeros(n, bool)
             arts = np.zeros(n, np.int32)
@@ -476,15 +562,19 @@ class CoherenceBroker:
             wmasks = self._measure_write_masks(batch)
             state_before = (np.asarray(self.decider.arrays.state,
                                        np.int32).copy()
-                            if tel is not None else None)
+                            if self.telemetry is not None else None)
             queue_depth = len(batch) + len(self._pending)
             ver_before = np.asarray(self.decider.arrays.version,
                                     np.int64).copy()
-        with span("broker.decide"):
-            t_decide = time.perf_counter()
-            decision = self.decider.decide(acts, arts, writes,
-                                           write_chunks=wmasks)
-            busy_s = time.perf_counter() - t_decide
+        return _Staged(batch, acts, arts, writes, t_submit, wmasks,
+                       state_before, queue_depth, ver_before)
+
+    def _finish(self, staged: _Staged, decision, t_decide: float,
+                busy_s: float) -> None:
+        """Checks, responses and telemetry of a decided batch."""
+        (batch, acts, arts, writes, t_submit, wmasks, state_before,
+         queue_depth, ver_before) = staged
+        tel = self.telemetry
         self.decide_busy_s += busy_s
 
         with span("broker.checks"):

@@ -10,15 +10,21 @@ artifact** across K broker shards (``configs.shard_of_artifact``):
     artifact's entire history (reads, upgrades, commits, invalidations)
     serializes through exactly one shard, so no cross-shard interleaving
     can ever produce two M holders;
-  * every shard is a full, unmodified ``CoherenceBroker`` pinned to its
-    own device (``launch.mesh.shard_devices``), so each shard's
-    micro-batches run through its own ``mesi_decision_batch`` /
-    ``apply_actions`` device program;
+  * every shard is a full ``CoherenceBroker`` pinned to its own device
+    (``launch.mesh.shard_devices``), so each shard's micro-batches run
+    through its own ``mesi_decision_dispatch`` / ``apply_actions`` device
+    program;
+  * ONE task flushes every shard in rounds: it cuts, stages and
+    dispatches the batch of every shard with requests pending, and only
+    then resolves each in shard order, so the shards' device programs
+    run at once while their host phases take turns.  A shard never has
+    two batches in flight;
   * the shards' interleaved batch commits are recorded into ONE global
-    ``ServiceTrace`` in event-loop commit order - a serializable order
-    the four-way oracle replays, and ``sim.oracle.check_sharded_trace``
-    additionally re-derives every shard's local history from it
-    (cross-shard conformance leg).
+    ``ServiceTrace`` in resolve order - a serializable order (an
+    artifact's whole history is on one shard) that the four-way oracle
+    replays, and ``sim.oracle.check_sharded_trace`` additionally
+    re-derives every shard's local history from it (cross-shard
+    conformance leg).
 
 In front of the L2 authority sits a per-host **L1 directory**
 (:class:`HostL1Directory`): each host caches the (version, content) it
@@ -37,6 +43,7 @@ entry surviving past the bound raises ``InvariantViolation``.
 
 from __future__ import annotations
 
+import asyncio
 import dataclasses
 import functools
 from typing import Dict, NamedTuple, Optional, Sequence
@@ -45,6 +52,7 @@ import numpy as np
 
 from repro.content.chunks import BYTES_PER_TOKEN
 from repro.core.protocol import TokenLedger
+from repro.obs.spans import span
 from repro.obs.stats import unified_stats
 from repro.obs.telemetry import Telemetry
 from repro.service.batching import resolve_decide_backend
@@ -165,6 +173,20 @@ class ShardedCoherenceBroker:
                 n_shards=self.n_shards,
                 n_hosts=config.topology.n_hosts)
 
+        #: one flush task for every shard (``_flusher``); any shard's
+        #: submit wakes it
+        self._wake = asyncio.Event()
+        self._flusher_task: Optional[asyncio.Task] = None
+        self._closed = False
+        if self.telemetry is not None:
+            reg = self.telemetry.registry
+            self._rounds = reg.counter(
+                "coh_shard_rounds_total",
+                "rounds of the sharded plane's flush task")
+            self._round_batches = reg.histogram(
+                "coh_shard_round_batches",
+                "shards' batches in flight together in one round")
+
         self.brokers = []
         for shard in range(self.n_shards):
             view = config.shard_view(shard)
@@ -182,7 +204,7 @@ class ShardedCoherenceBroker:
                 view.broker_view(), sub_contents,
                 on_commit=functools.partial(self._commit, shard),
                 device=devices[shard],
-                telemetry=self.telemetry, shard=shard))
+                telemetry=self.telemetry, shard=shard, wake=self._wake))
         self.brokers = tuple(self.brokers)
 
         self.l1 = tuple(
@@ -198,11 +220,18 @@ class ShardedCoherenceBroker:
     async def start(self) -> "ShardedCoherenceBroker":
         for broker in self.brokers:
             await broker.start()
+        if self._flusher_task is None:
+            self._flusher_task = asyncio.get_running_loop().create_task(
+                self._flusher())
         return self
 
     async def stop(self) -> None:
+        self._closed = True
         for broker in self.brokers:
             await broker.stop()
+        if self._flusher_task is not None:
+            await self._flusher_task
+            self._flusher_task = None
         if self.config.service.check_invariants:
             self.check_l1()
 
@@ -211,6 +240,46 @@ class ShardedCoherenceBroker:
 
     async def __aexit__(self, *exc) -> None:
         await self.stop()
+
+    # ---------------------------------------------------------- rounds
+    def _has_pending(self) -> bool:
+        return any(broker._pending for broker in self.brokers)
+
+    async def _flusher(self) -> None:
+        """The shards' one flush task: a plain broker's ``_flusher``,
+        with a round over every shard where it flushes one batch."""
+        window = self.config.service.batch_window
+        while True:
+            await self._wake.wait()
+            self._wake.clear()
+            if self._closed and not self._has_pending():
+                return
+            # one event-loop pass (or the batch window): every
+            # already-scheduled client coroutine enqueues first
+            await asyncio.sleep(window if window > 0 else 0)
+            while self._has_pending():
+                self._round()
+                if self._has_pending():     # same-agent spillover
+                    await asyncio.sleep(0)
+            if self._closed:
+                return
+
+    def _round(self) -> None:
+        """Dispatch the batch of every shard with requests pending, then
+        resolve each in shard order: every shard's device program is in
+        flight before the first readback waits on one."""
+        with span("broker.round"):
+            with span("broker.round.dispatch"):
+                flights = [(broker, broker._flush_begin())
+                           for broker in self.brokers if broker._pending]
+            with span("broker.round.resolve"):
+                for broker, flight in flights:
+                    if flight is not None:
+                        broker._flush_end(flight)
+        if self.telemetry is not None:
+            self._rounds.inc()
+            self._round_batches.observe(
+                sum(flight is not None for _, flight in flights))
 
     # ------------------------------------------------------ client API
     def shard_of(self, artifact: str) -> int:
@@ -230,7 +299,8 @@ class ShardedCoherenceBroker:
     async def read(self, agent: int, artifact: str) -> ReadResult:
         result = await self.broker_of(artifact).read(agent, artifact)
         if not result.hit:
-            self._attribute_fill(agent, artifact, result)
+            with span("broker.l1"):
+                self._attribute_fill(agent, artifact, result)
         return result
 
     async def write(self, agent: int, artifact: str,
@@ -238,7 +308,8 @@ class ShardedCoherenceBroker:
                     ) -> WriteResult:
         result = await self.broker_of(artifact).write(agent, artifact,
                                                       content)
-        self._l1_on_commit(agent, artifact, result.version)
+        with span("broker.l1"):
+            self._l1_on_commit(agent, artifact, result.version)
         return result
 
     # -------------------------------------------------------- L1 plane
@@ -253,9 +324,10 @@ class ShardedCoherenceBroker:
         """Attribute one coherence fill to the L1 or the L2 plane.
 
         Future resolution order IS the authority's serialization order
-        (batches commit in event-loop order; within a batch futures
-        resolve in ascending agent order), so this bookkeeping observes
-        commits exactly as the decision plane serialized them."""
+        (batches commit in the rounds' resolve order; within a batch
+        futures resolve in ascending agent order), so this bookkeeping
+        observes commits exactly as the decision plane serialized
+        them."""
         host = self.l1[self.host_of(agent)]
         host.check(artifact, result.version)
         entry = host.lookup(artifact)
@@ -311,7 +383,7 @@ class ShardedCoherenceBroker:
                 commit: dict) -> None:
         """Per-shard commit hook: remap the shard-local batch onto the
         global artifact index space and append it (tagged with its
-        shard) to the global trace, in event-loop commit order."""
+        shard) to the global trace, in the rounds' resolve order."""
         self.n_batches += 1
         if not self._capture:
             return
@@ -389,9 +461,11 @@ class ShardedCoherenceBroker:
 
     def decision_busy(self) -> tuple:
         """Per-shard seconds spent inside the decider - the serialized
-        per-authority bottleneck.  Under the shard-per-host deployment
-        the shards decide concurrently, so the plane's makespan is the
-        MAX over shards (the decision-capacity metric of the bench)."""
+        per-authority bottleneck.  Each round of the flush task has every
+        pending shard's device program in flight at once, while the
+        shards' host phases (staging, readback, outcomes) still take
+        turns on the one event loop; a shard's seconds are its own
+        dispatch and resolve, not its wait while others resolve."""
         return tuple(broker.decide_busy_s for broker in self.brokers)
 
     # ----------------------------------------------------------- stats

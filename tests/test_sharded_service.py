@@ -446,3 +446,167 @@ def test_trace_shard_roundtrip_and_back_compat():
     old = type(trace).from_json(json.dumps(payload))
     assert old.n_shards == 1 and old.artifact_shards == ()
     assert all(s.shard == -1 for s in old.steps)
+
+
+# ---------------------------------------------------------------------------
+# The flush rounds: every shard's batch in flight at once.
+
+
+def _serial_round(self):
+    """The plane as it was before its rounds: one whole flush per shard
+    in turn, each shard's batch resolved before the next is cut."""
+    for broker in self.brokers:
+        if broker._pending:
+            broker._flush_once()
+
+
+def _waves(n: int, m: int, rounds: int, seed: int = 11):
+    """Mixed reads and writes over every shard, one request per agent
+    per wave, a wave submitted at once."""
+    rng = np.random.default_rng(seed)
+    return [[(a, int(rng.integers(m)), bool(rng.random() < 0.3))
+             for a in range(n) if rng.random() < 0.8]
+            for _ in range(rounds)]
+
+
+def _serve_waves(cfg, waves, contents=None):
+    async def go():
+        async with connect(cfg, contents=contents) as broker:
+            answers = []
+            for wave in waves:
+                answers += await asyncio.gather(*(
+                    broker.write(a, cfg.artifacts[d]) if w
+                    else broker.read(a, cfg.artifacts[d])
+                    for a, d, w in wave))
+            return broker, answers
+    return asyncio.run(go())
+
+
+def _observed(broker, answers) -> tuple:
+    return ([(r.version, getattr(r, "hit", None)) for r in answers],
+            dataclasses.astuple(broker.ledger),
+            np.asarray(broker.directory_state).tolist(),
+            np.asarray(broker.versions).tolist(),
+            dict(broker.l1_wire), dict(broker.wire))
+
+
+@pytest.mark.parametrize("route,chunk", [("scan", 0), ("pallas", 0),
+                                         ("scan", 8)],
+                         ids=["scan", "pallas", "chunked"])
+def test_rounds_match_serial_flushes(monkeypatch, route, chunk):
+    """The flush rounds and one whole flush per shard in turn give
+    bit-identical answers, ledgers, directories, versions, L1
+    attribution and wire bytes."""
+    cfg = _config(n=8, m=8, tokens=32, shards=4, hosts=4, backend=route,
+                  chunk_tokens=chunk)
+    waves = _waves(8, 8, 10)
+    broker, answers = _serve_waves(cfg, waves)
+    assert broker._flusher_task is None and all(
+        b._flusher_task is None for b in broker.brokers)
+    driven = _observed(broker, answers)
+    monkeypatch.setattr(ShardedCoherenceBroker, "_round", _serial_round)
+    serial = _observed(*_serve_waves(cfg, waves))
+    assert driven == serial
+    assert driven[4]["l1_fills"] + driven[4]["l2_fills"] > 0
+
+
+def test_round_trace_passes_the_sharded_oracle():
+    cfg = _config(n=8, m=8, tokens=32, shards=4, hosts=4)
+    broker, _ = _serve_waves(cfg, _waves(8, 8, 12, seed=5))
+    trace = broker.trace
+    assert {s.shard for s in trace.steps} == {0, 1, 2, 3}
+    oracle.check_sharded_trace(trace.acs_config(), trace.to_oracle_trace(),
+                               trace.artifact_shards, name="rounds")
+    verify_broker(broker)
+
+
+def _rounds_of_records(monkeypatch):
+    """Wrap the plane's round so that each round's committed batch
+    records are kept together."""
+    rounds = []
+    do_round = ShardedCoherenceBroker._round
+
+    def round_(self):
+        before = len(self.telemetry.spans.records)
+        do_round(self)
+        rounds.append(list(self.telemetry.spans.records)[before:])
+    monkeypatch.setattr(ShardedCoherenceBroker, "_round", round_)
+    return rounds
+
+
+def test_round_dispatches_every_shard_before_any_readback(monkeypatch):
+    rounds = _rounds_of_records(monkeypatch)
+    cfg = _config(n=8, m=8, tokens=32, shards=4, hosts=4,
+                  backend="pallas")
+    broker, _ = _serve_waves(cfg, _waves(8, 8, 4, seed=3))
+    full = [r for r in rounds if len(r) == 4]
+    assert full, [len(r) for r in rounds]
+    for records in full:
+        assert [r.shard for r in records] == [0, 1, 2, 3]
+        calls = [r.phases["broker.decide.call"][0] for r in records]
+        readbacks = [r.phases["broker.decide.readback"][0]
+                     for r in records]
+        assert max(calls) < min(readbacks)
+    snap = broker.telemetry.registry
+    assert snap.counter_total("coh_shard_rounds_total") == len(rounds)
+    assert snap.histogram_totals("coh_shard_round_batches")[()] == (
+        len(rounds), sum(len(r) for r in rounds))
+
+
+def test_batch_record_holds_only_its_own_phases(monkeypatch):
+    rounds = _rounds_of_records(monkeypatch)
+    cfg = _config(n=8, m=8, tokens=32, shards=4, hosts=4)
+    broker, _ = _serve_waves(cfg, _waves(8, 8, 6, seed=9))
+    records = [r for rnd in rounds for r in rnd]
+    assert len(records) == len(broker.trace.steps)
+    # commits reach the global trace in resolve order
+    assert [r.shard for r in records] == [s.shard
+                                          for s in broker.trace.steps]
+    interleaved = 0
+    for rec, step in zip(records, broker.trace.steps):
+        assert not any(name.startswith("broker.round")
+                       for name in rec.phases)
+        # its first block's start, to the end of its last
+        assert rec.t0 == rec.phases["broker.batch"][0]
+        assert rec.t0 + rec.wall_s >= rec.phases["broker.batch"][1]
+        # its decide is its own two halves' stage, call, readback and
+        # outcomes, inside its two broker.decide blocks
+        assert rec.decide_s == step.decide_s
+        assert rec.decide_s <= rec.seconds("broker.decide")
+        assert all(rec.seconds(f"broker.decide.{p}") > 0
+                   for p in ("stage", "call", "readback", "outcomes"))
+        # the other shards' work between its halves is not its own
+        interleaved += rec.flush_s < rec.wall_s - 1e-9
+        assert rec.flush_s <= rec.wall_s
+    assert interleaved > 0
+
+
+def test_k1_flushes_once_per_batch_in_order(monkeypatch):
+    """A plain broker keeps its own flush task and runs each batch's
+    phases in one block, in the order it always did."""
+    calls = []
+    flush_once = CoherenceBroker._flush_once
+
+    def counted(self):
+        calls.append(1)
+        flush_once(self)
+    monkeypatch.setattr(CoherenceBroker, "_flush_once", counted)
+
+    async def go():
+        async with connect(n_agents=4, artifacts=("a", "b"),
+                           artifact_tokens=32, backend="scan") as broker:
+            assert type(broker) is CoherenceBroker
+            assert broker._flusher_task is not None
+            await asyncio.gather(broker.read(0, "a"), broker.write(1, "b"),
+                                 broker.read(2, "b"))
+            return broker
+    broker = asyncio.run(go())
+    (rec,) = broker.telemetry.spans.records
+    assert len(calls) >= 1 and broker.n_batches == 1
+    assert list(rec.phases) == [
+        "broker.cut", "broker.stage", "broker.decide.readback",
+        "broker.decide.stage", "broker.decide.call",
+        "broker.decide.outcomes", "broker.decide", "broker.checks",
+        "broker.respond", "broker.telemetry", "broker.batch"]
+    assert rec.t0 == rec.phases["broker.batch"][0]
+    assert 0 < rec.flush_s <= rec.wall_s

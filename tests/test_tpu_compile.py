@@ -78,6 +78,9 @@ MESI_SHAPES = {
     "oracle": (1, 32, 6, 128),
     "sweep": (256, 8, 6, 128),
     "fleet": (257, 256, 16, DECISION_BLOCK),
+    # the four-shard fleet's shards (crc32 placement: 3 or 5 artifacts)
+    "shard3": (257, 256, 3, DECISION_BLOCK),
+    "shard5": (257, 256, 5, DECISION_BLOCK),
 }
 
 
@@ -174,6 +177,7 @@ def test_kernels_carry_their_names(one_chip, kernel):
 
 
 @pytest.mark.parametrize("program", ["mesi_service", "mesi_fleet",
+                                     "mesi_shard3", "mesi_shard5",
                                      "chunk_service"])
 def test_served_programs_compile_for_v5e(one_chip, program):
     """The served path's jitted decision programs (the n+1 prefix tile,
