@@ -18,6 +18,11 @@ indexing.
 Counters layout (out[..., c]): 0 fetch_tokens, 1 signal_tokens,
 2 push_tokens, 3 n_fetches, 4 n_hits, 5 n_invalidation_signals;
 6-7 reserved (zero).
+
+The served path decides a live micro-batch on the single directory
+(one sim): with ``answers=True`` the kernel writes each acting agent's
+fill bit and served version at its serialization slot, so every
+request of the batch is answered by the one tick that applies it.
 """
 
 from __future__ import annotations
@@ -36,10 +41,6 @@ from repro.obs.spans import span
 
 _I, _S = int(MESIState.I), int(MESIState.S)
 N_COUNTERS = 8
-
-#: sims per grid step of ``mesi_decision_dispatch``: its n+1 prefix sims
-#: tile over a grid so that a 256-agent directory block stays within VMEM.
-DECISION_BLOCK = 8
 
 
 def episode_step_keys(keys: jax.Array, n_steps: int) -> jax.Array:
@@ -86,11 +87,14 @@ def _mesi_kernel(state_ref, version_ref, sync_ref, reads_ref,
                  state_out, version_out, sync_out, reads_out, counter_out,
                  miss_out,
                  *, n_agents: int, n_artifacts: int, artifact_tokens: int,
-                 eager: bool, access_k: int, signal_tokens: int):
+                 eager: bool, access_k: int, signal_tokens: int,
+                 answers: bool):
     # Every value is rank 3 with the sim axis leading: directory arrays
     # (bs, n, m), per-artifact rows (bs, 1, m), per-agent rows (bs, 1, n),
     # per-sim scalars (bs, 1, 1).  The carried directory lives in the
-    # output refs; the agent loop reads and rewrites them.
+    # output refs; the agent loop reads and rewrites them.  ``miss_out``
+    # is (bs, 1, n) fill bits, or with ``answers`` (bs, 2, n): row 0 the
+    # fill bit, row 1 the version the agent is synced to after its step.
     state_out[...] = state_ref[...]
     version_out[...] = version_ref[...]
     sync_out[...] = sync_ref[...]
@@ -101,6 +105,8 @@ def _mesi_kernel(state_ref, version_ref, sync_ref, reads_ref,
     agent_col = jax.lax.broadcasted_iota(jnp.int32, (bs, n_agents, 1), 1)
     agent_lane = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, n_agents), 2)
     art_lane = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, n_artifacts), 2)
+    if answers:
+        answer_row = jax.lax.broadcasted_iota(jnp.int32, miss_out.shape, 1)
 
     def agent_body(a, carry):
         row = agent_col == a                        # (bs, n, 1)
@@ -133,7 +139,8 @@ def _mesi_kernel(state_ref, version_ref, sync_ref, reads_ref,
                              (artifact_tokens + signal_tokens) * miss_i)
         counters = slot_add(counters, 3, miss_i)
         counters = slot_add(counters, 4, hit.astype(jnp.int32))
-        miss_out[...] = jnp.where(a_oh, miss_i, miss_out[...])
+        if not answers:
+            miss_out[...] = jnp.where(a_oh, miss_i, miss_out[...])
 
         # --- write path: invalidate peers, bump version, commit
         wmask = jnp.logical_and(is_write, d_oh)     # (bs, 1, m)
@@ -168,6 +175,13 @@ def _mesi_kernel(state_ref, version_ref, sync_ref, reads_ref,
         reads_out[...] = reads
         version_out[...] = new_ver
         counter_out[...] = counters
+        if answers:
+            # the agent's cell after its fill, write and pushes; later
+            # agents' steps never write its column again
+            served = jnp.where(act, pick_lane(d_oh, pick_row(row, sync)), 0)
+            miss_out[...] = jnp.where(
+                a_oh, jnp.where(answer_row == 0, miss_i, served),
+                miss_out[...])
         return carry
 
     jax.lax.fori_loop(0, n_agents, agent_body, 0)
@@ -177,6 +191,7 @@ def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
                      acts, arts, writes, *, artifact_tokens: int,
                      eager: bool = False, access_k: int = 0,
                      signal_tokens: int = 12, block_sims: int = 128,
+                     answers: bool = False,
                      interpret: bool | None = None):
     """One coherence tick over a batch of simulations.
 
@@ -186,8 +201,12 @@ def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
     coherence-fill indicator of this tick, which the chunk content
     plane (``repro.kernels.chunk_diff``) consumes to route delta
     fetches at the exact serialization slots the MESI decisions were
-    made at.  ``interpret=None`` auto-detects the backend (compiled
-    Mosaic on TPU, interpret mode elsewhere).
+    made at.  With ``answers=True`` (the served decision) the last
+    output is (B, 2, n) instead: the fill bits, then each acting
+    agent's served version - its synced version of its artifact right
+    after its own step, which later pushes in the tick do not change;
+    0 for agents that do not act.  ``interpret=None`` auto-detects the
+    backend (compiled Mosaic on TPU, interpret mode elsewhere).
     """
     interpret = resolve_interpret(interpret)
     B, n, m = state.shape
@@ -202,11 +221,11 @@ def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
     kernel = functools.partial(
         _mesi_kernel, n_agents=n, n_artifacts=m,
         artifact_tokens=artifact_tokens, eager=eager, access_k=access_k,
-        signal_tokens=signal_tokens)
+        signal_tokens=signal_tokens, answers=answers)
     blocks = [(bs, n, m), (bs, 1, m), (bs, n, m), (bs, n, m),
               (bs, 1, n), (bs, 1, n), (bs, 1, n)]
     out_blocks = [(bs, n, m), (bs, 1, m), (bs, n, m), (bs, n, m),
-                  (bs, 1, N_COUNTERS), (bs, 1, n)]
+                  (bs, 1, N_COUNTERS), (bs, 2 if answers else 1, n)]
     spec = lambda blk: pl.BlockSpec(blk, lambda i: (i, 0, 0))
     out = pl.pallas_call(
         kernel,
@@ -219,45 +238,37 @@ def mesi_tick_pallas(state, version, last_sync, reads_since_fetch,
         name="mesi_tick",
     )(*args)
     st, ver, sy, rd, cnt, miss = (o[:B] for o in out)
-    return st, ver[:, 0], sy, rd, cnt[:, 0], miss[:, 0]
+    return st, ver[:, 0], sy, rd, cnt[:, 0], miss if answers else miss[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "artifact_tokens", "eager", "access_k", "signal_tokens", "interpret"))
 def mesi_decision_program(state, version, last_sync, reads_since_fetch,
-                          acts_b, arts, writes, *, artifact_tokens: int,
+                          acts, arts, writes, *, artifact_tokens: int,
                           eager: bool, access_k: int, signal_tokens: int,
                           interpret: bool):
     """The device work of :func:`mesi_decision_dispatch`, one program per
-    static config and shape: the single directory and ``arts``/``writes``
-    (n,) tiled to the ``B`` prefix sims of ``acts_b`` (B, n), one
-    ``mesi_tick_pallas`` call, and the full batch's row (the last sim).
+    static config and shape: one ``mesi_tick_pallas`` call with
+    ``answers=True`` on the single directory (a unit sim axis).
 
     Returns ``((state', version', sync', reads', counters (8,)),
-    counters (B, 8), sync (B, n, m))``: the full-batch transition, then
-    each sim's counters and sync cells for the per-request outcomes.
+    answers (2, n))``: the full-batch transition, then each agent's
+    fill bit and served version.
     """
-    B = acts_b.shape[0]
-    tile = lambda arr: jnp.broadcast_to(arr, (B,) + arr.shape)
-    st, ver, sy, rd, cnt, _ = mesi_tick_pallas(
-        tile(state), tile(version), tile(last_sync),
-        tile(reads_since_fetch), acts_b, tile(arts), tile(writes),
+    st, ver, sy, rd, cnt, ans = mesi_tick_pallas(
+        state[None], version[None], last_sync[None],
+        reads_since_fetch[None], acts[None], arts[None], writes[None],
         artifact_tokens=artifact_tokens, eager=eager, access_k=access_k,
-        signal_tokens=signal_tokens, block_sims=DECISION_BLOCK,
-        interpret=interpret)
-    return (st[-1], ver[-1], sy[-1], rd[-1], cnt[-1]), cnt, sy
+        signal_tokens=signal_tokens, answers=True, interpret=interpret)
+    return (st[0], ver[0], sy[0], rd[0], cnt[0]), ans[0]
 
 
 class DecisionInFlight(NamedTuple):
     """A decision batch :func:`mesi_decision_dispatch` sent to the device
-    and :func:`mesi_decision_resolve` has not read back yet.
-    ``cnt``/``sy`` are ``None`` for an empty batch."""
+    and :func:`mesi_decision_resolve` has not read back yet."""
 
-    order: np.ndarray    # acting agents, ascending
-    arts: np.ndarray     # (n,) artifact per agent slot
     full: tuple          # the full-batch transition, on the device
-    cnt: object          # (B, 8) counters of the prefix sims
-    sy: object           # (B, n, m) sync cells of the prefix sims
+    answers: object      # (2, n) fill bits and served versions
 
 
 def mesi_decision_dispatch(state, version, last_sync, reads_since_fetch,
@@ -266,85 +277,46 @@ def mesi_decision_dispatch(state, version, last_sync, reads_since_fetch,
                            signal_tokens: int = 12,
                            interpret: bool | None = None
                            ) -> DecisionInFlight:
-    """Dispatch one micro-batch of live coherence decisions via
-    prefix-replicated simulations (the ``repro.service.batching`` kernel
-    route); :func:`mesi_decision_resolve` reads the answers back.
+    """Dispatch one micro-batch of live coherence decisions (the
+    ``repro.service.batching`` kernel route); :func:`mesi_decision_resolve`
+    reads the answers back.
 
-    The kernel emits per-*simulation* aggregate counters, not
-    per-request outcomes, yet a live broker must answer each request
-    individually (fill vs hit, served version).  Trick: replicate the
-    single directory into ``B = k+1`` sims where sim ``j`` enables only
-    the first ``j`` active agents (in the authority's ascending-agent
-    serialization order).  Agent processing is sequential and
-    deterministic, so sim ``j`` agrees with the full batch on its
-    prefix, and request ``j``'s outcome is the counter delta between
-    consecutive prefix sims - every decision of the batch falls out of
-    ONE ``mesi_tick_pallas`` call, vectorized over the sim lanes the
-    kernel already batches on.
+    A live broker must answer each request individually (fill vs hit,
+    served version).  The kernel processes agents one at a time in the
+    authority's ascending-agent serialization order, so it writes each
+    acting agent's answers at its own slot of the one tick that applies
+    the whole batch.
 
     Inputs: single-directory arrays - ``state``/``last_sync``/``reads``
     (n, m) int32, ``version`` (m,) int32 - plus the request vectors
     ``acts``/``arts``/``writes`` (n,) (at most one request per agent).
-    Stages the prefix sims and dispatches the program, up to the end of
-    ``broker.decide.call``: its outputs stay on the device, unread, so
-    a caller may dispatch other devices' batches before it resolves
-    this one.
+    Stages the request vectors and dispatches the program, up to the
+    end of ``broker.decide.call``: its outputs stay on the device,
+    unread, so a caller may dispatch other devices' batches before it
+    resolves this one.
     """
-    n, m = state.shape
-    acts_np = np.asarray(acts, bool)
-    order = np.flatnonzero(acts_np)          # ascending agent order
-    k = int(order.size)
-    if k == 0:
-        zc = jnp.zeros((N_COUNTERS,), jnp.int32)
-        return DecisionInFlight(order, np.asarray(arts, np.int32),
-                                (state, version, last_sync,
-                                 reads_since_fetch, zc), None, None)
     # The served decide's phases are with-blocks in place (a frame more
     # adds host time to every batch; the program itself is built once).
     with span("broker.decide.stage"):
-        # sim j enables the first j requests; sim 0 is the no-op
-        # baseline.  B is padded to the FIXED n+1 (rows past k repeat
-        # the full batch, so their counter deltas are zero) - every
-        # micro-batch size shares one compiled program instead of one
-        # Mosaic compile per distinct k.
-        acts_b = np.zeros((n + 1, n), np.int32)
-        for j, a in enumerate(order):
-            acts_b[j + 1:, a] = 1
+        acts_i = np.asarray(acts, np.int32)
         arts_i = np.asarray(arts, np.int32)
         writes_i = np.asarray(writes, np.int32)
     with span("broker.decide.call"):
-        full, cnt, sy = mesi_decision_program(
-            state, version, last_sync, reads_since_fetch, acts_b, arts_i,
+        full, answers = mesi_decision_program(
+            state, version, last_sync, reads_since_fetch, acts_i, arts_i,
             writes_i, artifact_tokens=artifact_tokens, eager=eager,
             access_k=access_k, signal_tokens=signal_tokens,
             interpret=resolve_interpret(interpret))
-    return DecisionInFlight(order, arts_i, full, cnt, sy)
+    return DecisionInFlight(full, answers)
 
 
 def mesi_decision_resolve(flight: DecisionInFlight) -> tuple:
-    """Read a dispatched batch's prefix sims back (waiting out the
-    program) and derive each request's outcome.  Returns ``(state',
-    version', sync', reads', counters (8,), miss (n,) bool,
-    served_version (n,) int32)`` where the primed arrays/counters are
-    the full-batch transition."""
-    n = flight.arts.shape[0]
-    if flight.cnt is None:
-        return flight.full + (jnp.zeros((n,), bool),
-                              jnp.zeros((n,), jnp.int32))
+    """Read a dispatched batch's counters and answers back (waiting out
+    the program).  Returns ``(state', version', sync', reads',
+    counters (8,), miss (n,) bool, served_version (n,) int32)``: the
+    full-batch directory stays on the device; the counters, fill bits
+    and served versions are host arrays."""
+    *arrays, cnt = flight.full
     with span("broker.decide.readback"):
-        cnt_np = np.asarray(flight.cnt, np.int64)
-        sync_np = np.asarray(flight.sy, np.int64)
-    with span("broker.decide.outcomes"):
-        arts_np = np.asarray(flight.arts, np.int64)
-        miss = np.zeros((n,), bool)
-        served = np.zeros((n,), np.int32)
-        for j, a in enumerate(flight.order):
-            # counter slot 3 = n_fetches; the delta between prefix j+1
-            # and prefix j is exactly request j's fill.
-            miss[a] = (cnt_np[j + 1, 3] - cnt_np[j, 3]) == 1
-            # sim j+1 processed request j last: its sync cell is the
-            # version agent a is synced to at its serialization slot
-            # (later eager pushes in the full batch must not leak into
-            # this answer).
-            served[a] = sync_np[j + 1, a, arts_np[a]]
-        return flight.full + (jnp.asarray(miss), jnp.asarray(served))
+        cnt_np, answers = jax.device_get((cnt, flight.answers))
+    return (*arrays, cnt_np, answers[0] != 0, answers[1])

@@ -16,9 +16,9 @@ simulator (and therefore with the four-way differential oracle):
               pattern as ``repro.sim.engine``).  Covers every
               invalidation strategy plus K-staleness enforcement.
   ``pallas``  one ``kernels.mesi_transition.mesi_decision_dispatch`` pass:
-              the batched MESI transition kernel over prefix-replicated
-              sims, which yields per-request outcomes from the kernel's
-              own counters.  Covers the differential strategies
+              the MESI transition kernel on the single directory, which
+              writes each request's fill bit and served version at its
+              serialization slot.  Covers the differential strategies
               (lazy / eager / access_count) with ``max_stale_steps=0``;
               staleness diagnostics are scan-route-only, mirroring the
               oracle's Pallas scope note.  Its device work, and the
@@ -295,15 +295,13 @@ class BatchDecider:
     def _resolve_pallas(self, flight: InFlight) -> BatchDecision:
         acts, arts, writes, write_chunks = flight[:4]
         a = self.arrays
-        # mesi_decision_resolve times its own readback and outcomes
+        # mesi_decision_resolve times its own readback
         st, ver, sy, rd, cnt, miss, served = mesi_decision_resolve(
             flight.device)
         acts_np = np.asarray(acts, bool)
         writes_np = np.asarray(writes, bool)
-        with span("broker.decide.readback"):
-            cnt_np = np.asarray(cnt, np.int64)
         with span("broker.decide.outcomes"):
-            delta = {f: int(cnt_np[slot])
+            delta = {f: int(cnt[slot])
                      for f, slot in _KERNEL_SLOTS.items()}
             # the kernel tracks token counters only; action counts come
             # from the batch itself (same derivation as
@@ -334,7 +332,7 @@ class BatchDecider:
                 wact = (acts_np & writes_np).astype(np.int32)
                 chunk_args = (
                     self.arrays.chunk_version, self.arrays.chunk_sync,
-                    self.arrays.chunk_dirty, np.asarray(miss, np.int32),
+                    self.arrays.chunk_dirty, miss.astype(np.int32),
                     wact, np.asarray(arts, np.int32),
                     np.asarray(write_chunks, np.int32))
             with span("broker.decide.call"):
@@ -356,8 +354,6 @@ class BatchDecider:
                 self.metrics = self.metrics._replace(**{
                     f: getattr(self.metrics, f) + wire[f]
                     for f in _WIRE_FIELDS})
-        with span("broker.decide.readback"):
-            return BatchDecision(miss=np.asarray(miss, bool),
-                                 version=np.asarray(served, np.int32),
-                                 ledger_delta=delta,
-                                 fetched_chunks=fetched, wire_delta=wire)
+        return BatchDecision(miss=miss, version=served,
+                             ledger_delta=delta, fetched_chunks=fetched,
+                             wire_delta=wire)

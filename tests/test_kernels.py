@@ -17,7 +17,8 @@ from repro.core import acs
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.mesi_transition import mesi_tick_pallas
+from repro.kernels.mesi_transition import (mesi_decision_program,
+                                           mesi_tick_pallas)
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
 TOLS = {jnp.float32: dict(rtol=1e-5, atol=1e-5),
@@ -225,3 +226,117 @@ class TestMESITickKernel:
                                artifact_tokens=16, interpret=True)
         state = np.asarray(out[0])
         assert ((state == 3).sum(axis=1) <= 1).all()
+
+
+#: (eager, access_k) of the three strategies the served kernel route takes
+_STRATEGIES = {"lazy": (False, 0), "eager": (True, 0),
+               "access_count": (False, 2)}
+
+
+def _prefix_answers(directory, acts, arts, writes, **kw):
+    """Each request's fill bit and served version the way the served
+    route once derived them: ``n+1`` copies of the one directory, copy
+    ``j`` enabling only the first ``j`` acting agents, through the
+    kernel with its answers off; request ``j`` filled iff the fetch
+    counter rose from copy ``j`` to ``j+1``, and was served the synced
+    cell copy ``j+1`` ends with."""
+    n = acts.shape[0]
+    order = np.flatnonzero(acts)
+    acts_b = np.zeros((n + 1, n), np.int32)
+    for j, a in enumerate(order):
+        acts_b[j + 1:, a] = 1
+    tile = lambda x: jnp.broadcast_to(x, (n + 1,) + x.shape)  # noqa: E731
+    state, version, sync, reads = directory
+    out = mesi_tick_pallas(tile(state), tile(version), tile(sync),
+                           tile(reads), jnp.asarray(acts_b), tile(arts),
+                           tile(writes), block_sims=8, interpret=True, **kw)
+    cnt, sy = np.asarray(out[4]), np.asarray(out[2])
+    miss = np.zeros((n,), bool)
+    served = np.zeros((n,), np.int32)
+    for j, a in enumerate(order):
+        miss[a] = cnt[j + 1, 3] - cnt[j, 3] == 1
+        served[a] = sy[j + 1, a, arts[a]]
+    return [o[-1] for o in out[:5]], miss, served
+
+
+def _decide(directory, acts, arts, writes, **kw):
+    full, answers = mesi_decision_program(
+        *directory, jnp.asarray(acts), jnp.asarray(arts),
+        jnp.asarray(writes), signal_tokens=12, interpret=True, **kw)
+    return full, np.asarray(answers)
+
+
+class TestServedDecisionAnswers:
+    """The served decision's one-sim kernel writes every request's fill
+    bit and served version, as the n+1 prefix sims derived them."""
+
+    @pytest.mark.parametrize("strategy", list(_STRATEGIES))
+    def test_one_sim_answers_match_prefix_derivation(self, strategy):
+        eager, access_k = _STRATEGIES[strategy]
+        kw = dict(artifact_tokens=64, eager=eager, access_k=access_k)
+        n, m = 9, 3
+        rng = np.random.default_rng(17)
+        directory = (jnp.zeros((n, m), jnp.int32), jnp.ones((m,), jnp.int32),
+                     jnp.zeros((n, m), jnp.int32),
+                     jnp.zeros((n, m), jnp.int32))
+        for _ in range(8):
+            acts = (rng.random(n) < 0.7).astype(np.int32)
+            arts = rng.integers(0, m, n).astype(np.int32)
+            writes = (rng.random(n) < 0.3).astype(np.int32)
+            last, miss, served = _prefix_answers(directory, acts, arts,
+                                                 writes, **kw)
+            full, answers = _decide(directory, acts, arts, writes, **kw)
+            assert answers.shape == (2, n)
+            np.testing.assert_array_equal(answers[0], miss.astype(np.int32))
+            np.testing.assert_array_equal(answers[1], served)
+            for x, y in zip(full, last):
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+            directory = full[:4]
+
+    def test_served_version_precedes_a_later_eager_push(self):
+        """Agent 0 reads artifact 0, then agent 2 writes it: the push
+        moves agent 0's synced cell to the new version, but agent 0 was
+        served the version it read."""
+        kw = dict(artifact_tokens=64, eager=True, access_k=0)
+        n, m = 4, 2
+        directory = (jnp.zeros((n, m), jnp.int32), jnp.ones((m,), jnp.int32),
+                     jnp.zeros((n, m), jnp.int32),
+                     jnp.zeros((n, m), jnp.int32))
+        acts = np.array([1, 0, 1, 0], np.int32)
+        arts = np.array([0, 0, 0, 1], np.int32)
+        writes = np.array([0, 0, 1, 0], np.int32)
+        _, miss, served = _prefix_answers(directory, acts, arts, writes,
+                                          **kw)
+        full, answers = _decide(directory, acts, arts, writes, **kw)
+        final_sync = np.asarray(full[2])
+        assert final_sync[0, 0] == 2 and int(full[4][2]) > 0   # pushed
+        np.testing.assert_array_equal(answers[1], [1, 0, 2, 0])
+        np.testing.assert_array_equal(answers[1], served)
+        np.testing.assert_array_equal(answers[0], [1, 0, 1, 0])
+        np.testing.assert_array_equal(answers[0], miss.astype(np.int32))
+
+    @pytest.mark.parametrize("strategy", list(_STRATEGIES))
+    def test_answers_off_keeps_the_sweep_outputs(self, strategy):
+        """At the sweep's shape the kernel without answers returns the
+        numpy oracle's transition and (B, n) fill bits, the same the
+        answering kernel writes in its first row."""
+        eager, access_k = _STRATEGIES[strategy]
+        kw = dict(artifact_tokens=4096, eager=eager, access_k=access_k,
+                  block_sims=128, interpret=True)
+        inputs = _random_tick_inputs(np.random.default_rng(5), 256, 8, 6)
+        off = mesi_tick_pallas(*[jnp.asarray(x) for x in inputs], **kw)
+        on = mesi_tick_pallas(*[jnp.asarray(x) for x in inputs],
+                              answers=True, **kw)
+        assert off[5].shape == (256, 8) and on[5].shape == (256, 2, 8)
+        for x, y in zip(off[:5], on[:5]):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        np.testing.assert_array_equal(np.asarray(off[5]),
+                                      np.asarray(on[5][:, 0]))
+        exp = ref.mesi_tick_ref(*inputs, artifact_tokens=4096, eager=eager,
+                                access_k=access_k)
+        for x, y in zip(off[:4], exp[:4]):
+            np.testing.assert_array_equal(np.asarray(x), y)
+        np.testing.assert_array_equal(np.asarray(off[4][:, 3]),
+                                      exp[4]["n_fetches"])
+        np.testing.assert_array_equal(
+            np.asarray(off[5]).sum(axis=1), exp[4]["n_fetches"])

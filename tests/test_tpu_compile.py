@@ -22,8 +22,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.kernels import backend
 from repro.kernels.chunk_diff import chunk_tick_pallas
-from repro.kernels.mesi_transition import (DECISION_BLOCK,
-                                           mesi_tick_pallas)
+from repro.kernels.mesi_transition import mesi_tick_pallas
 from repro.sim import cliff_scenario, engine
 
 pytestmark = pytest.mark.pallas
@@ -69,28 +68,28 @@ def _kernel_ops(compiled) -> list:
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
-#: (B sims, n agents, m artifacts, block): the served decision batch at
-#: the service benchmark's 32 agents (n+1 prefix sims), the oracle's one
-#: sim, the sweep route, and the decision batch at the kernel's
-#: documented fleet limit.
+#: (B sims, n agents, m artifacts, block, answers): the served decision
+#: on one directory (answers on) at the service benchmark's 32 agents,
+#: the oracle's one sim, the sweep route, and the served decision at the
+#: kernel's documented fleet limit.
 MESI_SHAPES = {
-    "service": (33, 32, 6, DECISION_BLOCK),
-    "oracle": (1, 32, 6, 128),
-    "sweep": (256, 8, 6, 128),
-    "fleet": (257, 256, 16, DECISION_BLOCK),
+    "service": (1, 32, 6, 128, True),
+    "oracle": (1, 32, 6, 128, False),
+    "sweep": (256, 8, 6, 128, False),
+    "fleet": (1, 256, 16, 128, True),
     # the four-shard fleet's shards (crc32 placement: 3 or 5 artifacts)
-    "shard3": (257, 256, 3, DECISION_BLOCK),
-    "shard5": (257, 256, 5, DECISION_BLOCK),
+    "shard3": (1, 256, 3, 128, True),
+    "shard5": (1, 256, 5, 128, True),
 }
 
 
 @pytest.mark.parametrize("strategy", ["lazy", "eager", "access_count"])
 @pytest.mark.parametrize("shape", list(MESI_SHAPES))
 def test_mesi_tick_compiles_for_v5e(one_chip, shape, strategy):
-    B, n, m, block = MESI_SHAPES[shape]
+    B, n, m, block, answers = MESI_SHAPES[shape]
     kw = dict(artifact_tokens=4096, eager=strategy == "eager",
               access_k=3 if strategy == "access_count" else 0,
-              block_sims=block, interpret=False)
+              block_sims=block, answers=answers, interpret=False)
     args = [_i32(s, one_chip) for s in
             ((B, n, m), (B, m), (B, n, m), (B, n, m), (B, n), (B, n),
              (B, n))]
@@ -157,11 +156,12 @@ def test_kernels_carry_their_names(one_chip, kernel):
     from bench.tracing import custom_call_outputs
 
     if kernel == "mesi_tick":
-        B, n, m, block = MESI_SHAPES["fleet"]
+        B, n, m, block, answers = MESI_SHAPES["fleet"]
         shapes = ((B, n, m), (B, m), (B, n, m), (B, n, m), (B, n), (B, n),
                   (B, n))
         fn = lambda *a: mesi_tick_pallas(    # noqa: E731
-            *a, artifact_tokens=4096, block_sims=block, interpret=False)
+            *a, artifact_tokens=4096, block_sims=block, answers=answers,
+            interpret=False)
     else:
         B, n, m, C, block = CHUNK_SHAPES["service"]
         shapes = ((B, m, C), (B, n, m, C), (B, m, C), (B, n), (B, n),
@@ -180,9 +180,10 @@ def test_kernels_carry_their_names(one_chip, kernel):
                                      "mesi_shard3", "mesi_shard5",
                                      "chunk_service"])
 def test_served_programs_compile_for_v5e(one_chip, program):
-    """The served path's jitted decision programs (the n+1 prefix tile,
-    the kernel and the full-batch row; the content plane's one-sim chunk
-    tick) each hold exactly one named kernel call on a v5e chip."""
+    """The served path's jitted decision programs (the one-sim MESI tick
+    with its answers, the content plane's one-sim chunk tick) each hold
+    exactly one named kernel call on a v5e chip, with the output count
+    the benchmark finds the kernel by."""
     from bench.runners.open_loop import KERNELS
     from bench.tracing import custom_call_outputs
     from repro.kernels.mesi_transition import mesi_decision_program
@@ -190,8 +191,8 @@ def test_served_programs_compile_for_v5e(one_chip, program):
 
     if program.startswith("mesi"):
         kernel = "mesi_tick"
-        B, n, m, _ = MESI_SHAPES[program.split("_")[1]]
-        shapes = ((n, m), (m,), (n, m), (n, m), (B, n), (n,), (n,))
+        _, n, m, _, _ = MESI_SHAPES[program.split("_")[1]]
+        shapes = ((n, m), (m,), (n, m), (n, m), (n,), (n,), (n,))
         lowered = mesi_decision_program.lower(
             *[_i32(s, one_chip) for s in shapes], artifact_tokens=4096,
             eager=False, access_k=0, signal_tokens=12, interpret=False)
