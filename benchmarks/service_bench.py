@@ -45,8 +45,8 @@ import jax
 from benchmarks.common import (BenchRow, PhaseClock, bench_iters,
                                bench_steps, fast_mode, fmt_pct, md_table,
                                provenance, write_results)
-from repro.service import (BrokerConfig, CoherenceBroker, CoherenceConfig,
-                           connect, drive_workload, verify_broker)
+from repro.service import (CoherenceBroker, CoherenceConfig, connect,
+                           drive_workload, verify_broker)
 from repro.service.batching import resolve_decide_backend
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -80,11 +80,11 @@ def _workload(family: str, n_rounds: int):
         seed=FAMILY_SEEDS[family])
 
 
-def _broker_config(telemetry: bool = True) -> BrokerConfig:
+def _broker_config(telemetry: bool = True) -> CoherenceConfig:
     return CoherenceConfig.make(
         N_CLIENTS, tuple(f"artifact-{d}" for d in range(N_ARTIFACTS)),
         artifact_tokens=ARTIFACT_TOKENS, strategy=STRATEGY,
-        telemetry=telemetry).broker_view()
+        telemetry=telemetry)
 
 
 def _coherence_config(shards: int) -> CoherenceConfig:

@@ -1,11 +1,8 @@
 """Layered coherence configuration: ONE config surface for the
 protocol core, the service plane, and the shard topology.
 
-Before this module, ``repro.core.acs.ACSConfig`` and
-``repro.service.BrokerConfig`` had drifted into duplicated fields
-(``chunk_tokens``, the staleness bound, strategy knobs) that had to be
-kept in sync by hand.  :class:`CoherenceConfig` is now the single
-source of truth, layered the way the system is layered:
+:class:`CoherenceConfig` is the single source of truth, layered the way
+the system is layered:
 
   ``core``      protocol knobs every layer shares (strategy, artifact
                 slot size, access-count K, staleness bound, chunk
@@ -17,19 +14,21 @@ source of truth, layered the way the system is layered:
                 directories) - only the sharded authority plane reads
                 these.
 
-``BrokerConfig`` survives as a *thin frozen view* over the first two
-layers (``CoherenceConfig.broker_view()``); constructing it directly
-still works but warns once per process (deprecation shim - golden
-ledgers stay byte-identical either way).
+Every rule a servable deployment obeys is checked here, once.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import zlib
-from typing import Optional, Tuple
+from typing import Tuple
 
 from repro.core import acs
+
+#: strategies the broker serves.  Broadcast is the *baseline* the bench
+#: compares against analytically; TTL epochs are defined in terms of the
+#: simulator's logical step clock, which a live service does not have.
+BROKER_STRATEGIES = ("lazy", "eager", "access_count")
 
 
 def shard_of_artifact(name: str, n_shards: int) -> int:
@@ -52,10 +51,6 @@ class CoherenceCore:
     chunk_tokens: int = 0        # 0 = whole-artifact payloads
 
     def __post_init__(self):
-        if self.strategy not in acs.STRATEGY_CODES:
-            raise ValueError(
-                f"unknown strategy {self.strategy!r}; known: "
-                f"{sorted(acs.STRATEGY_CODES)}")
         if self.artifact_tokens <= 0:
             raise ValueError("artifact_tokens must be positive")
 
@@ -153,10 +148,34 @@ class CoherenceConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "artifacts", tuple(self.artifacts))
+        core = self.core
         if self.n_agents < 1:
             raise ValueError("n_agents must be >= 1")
+        if core.strategy not in BROKER_STRATEGIES:
+            raise ValueError(
+                f"broker serves {BROKER_STRATEGIES}, got "
+                f"{core.strategy!r} (broadcast is the baseline, not a "
+                f"servable strategy; ttl is simulation-clock-only)")
         if len(set(self.artifacts)) != len(self.artifacts):
             raise ValueError("duplicate artifact ids")
+        if core.chunk_tokens > 0:
+            if acs.STRATEGY_CODES[
+                    core.strategy] not in acs.CONTENT_STRATEGIES:
+                raise ValueError(
+                    f"chunked broker serves "
+                    f"{[acs.STRATEGY_NAMES[s] for s in acs.CONTENT_STRATEGIES]}"
+                    f" (delta fetch is pull-only); got "
+                    f"{core.strategy!r}")
+            if core.max_stale_steps > 0:
+                # the byte-exact oracle leg (verify_broker_content)
+                # covers max_stale_steps=0 only; allowing the combo
+                # would build a broker that can never be verified
+                raise ValueError(
+                    "chunked broker does not support K-staleness "
+                    "enforcement (max_stale_steps > 0): the byte-exact "
+                    "content oracle covers the pull-only invalidation "
+                    "protocol without revalidation; run either "
+                    "chunk_tokens=0 or max_stale_steps=0")
         if self.topology.assignment and len(
                 self.topology.assignment) != len(self.artifacts):
             raise ValueError(
@@ -202,31 +221,6 @@ class CoherenceConfig:
                    service=ServiceLayer(**svc_kw),
                    topology=ShardTopology(**topo_kw))
 
-    # ----------------------------------------------------- flat core view
-    # Read-only pass-throughs so code holding a broker handle can read
-    # the cost-model knobs without caring which config flavor (flat
-    # BrokerConfig vs layered) the topology handed it.
-
-    @property
-    def artifact_tokens(self) -> int:
-        return self.core.artifact_tokens
-
-    @property
-    def strategy(self) -> str:
-        return self.core.strategy
-
-    @property
-    def access_k(self) -> int:
-        return self.core.access_k
-
-    @property
-    def max_stale_steps(self) -> int:
-        return self.core.max_stale_steps
-
-    @property
-    def chunk_tokens(self) -> int:
-        return self.core.chunk_tokens
-
     # ------------------------------------------------------- projections
     def acs_config(self, n_steps: int = 1) -> acs.ACSConfig:
         """Project the core layer onto the simulator's static config."""
@@ -237,13 +231,6 @@ class CoherenceConfig:
             access_k=self.core.access_k,
             max_stale_steps=self.core.max_stale_steps,
             chunk_tokens=self.core.chunk_tokens)
-
-    def broker_view(self):
-        """The flat frozen ``BrokerConfig`` view of the core + service
-        layers (what a single broker shard consumes).  Constructed
-        through the blessed path, so no deprecation warning fires."""
-        from repro.service.broker import BrokerConfig
-        return BrokerConfig._from_layers(self)
 
     # ---------------------------------------------------------- topology
     def shard_of(self, artifact_index: int) -> int:
@@ -270,25 +257,3 @@ class CoherenceConfig:
             self, artifacts=tuple(self.artifacts[d] for d in cols),
             topology=ShardTopology())
 
-
-def from_broker_fields(n_agents: int, artifacts, *, artifact_tokens,
-                       strategy, access_k, max_stale_steps, batch_window,
-                       max_batch, backend, check_invariants,
-                       capture_trace, latency_window, chunk_tokens,
-                       telemetry: bool = True,
-                       topology: Optional[ShardTopology] = None,
-                       ) -> CoherenceConfig:
-    """Lift legacy flat ``BrokerConfig`` fields into the layered config
-    (the deprecation shim's upgrade path)."""
-    return CoherenceConfig(
-        n_agents=n_agents, artifacts=tuple(artifacts),
-        core=CoherenceCore(
-            artifact_tokens=artifact_tokens, strategy=strategy,
-            access_k=access_k, max_stale_steps=max_stale_steps,
-            chunk_tokens=chunk_tokens),
-        service=ServiceLayer(
-            batch_window=batch_window, max_batch=max_batch,
-            backend=backend, check_invariants=check_invariants,
-            capture_trace=capture_trace, latency_window=latency_window,
-            telemetry=telemetry),
-        topology=topology or ShardTopology())

@@ -16,9 +16,8 @@ from repro.kernels.ops import (rmsnorm, flash_attention, decode_attention,
                                mesi_tick)
 from repro.kernels import ref
 from repro.kernels.backend import interpret_default, resolve_interpret
-from repro.kernels.chunk_diff import (chunk_tick_pallas, chunk_tick_ref,
-                                      resolve_chunk_route)
+from repro.kernels.chunk_diff import chunk_tick_pallas, chunk_tick_ref
 
 __all__ = ["rmsnorm", "flash_attention", "decode_attention", "mesi_tick",
-           "chunk_tick_pallas", "chunk_tick_ref", "resolve_chunk_route",
+           "chunk_tick_pallas", "chunk_tick_ref",
            "ref", "interpret_default", "resolve_interpret"]

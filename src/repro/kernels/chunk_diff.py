@@ -28,16 +28,13 @@ Counters layout (out[..., c]): 0 delta_bytes (shipped), 1 full_bytes
 
 Routing matches ``mesi_tick``: ``interpret=None`` auto-detects via
 ``repro.kernels.backend`` (compiled Mosaic on TPU, interpret mode
-elsewhere); ``REPRO_CHUNK_DIFF=scan|pallas`` forces the pure-jnp
-reference (``chunk_tick_ref``, bit-identical by construction and by
-the byte-exact oracle) or the kernel in the service decision layer and
-anywhere :func:`resolve_chunk_route` is consulted.
+elsewhere).  ``chunk_tick_ref`` is the pure-numpy reference the kernel
+is tested bit-identical against.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -49,18 +46,6 @@ from repro.kernels.backend import resolve_interpret
 from repro.kernels.mesi_transition import pick_lane, pick_row, slot_add
 
 N_CHUNK_COUNTERS = 4
-
-
-def resolve_chunk_route(default: str = "auto") -> str:
-    """'scan' (pure-jnp reference) | 'pallas' for content-plane ticks
-    outside the fused engine (the engine follows ``REPRO_SIM_TICK``).
-    Forced with ``REPRO_CHUNK_DIFF``; ``auto`` follows the caller's
-    default."""
-    forced = os.environ.get("REPRO_CHUNK_DIFF", default)
-    if forced not in ("auto", "scan", "pallas"):
-        raise ValueError(f"REPRO_CHUNK_DIFF must be auto|scan|pallas, "
-                         f"got {forced!r}")
-    return default if forced == "auto" else forced
 
 
 def _chunk_kernel(cv_ref, cs_ref, dirty_ref, miss_ref, wact_ref, art_ref,
@@ -181,12 +166,10 @@ def chunk_tick_pallas(chunk_version, chunk_sync, chunk_dirty,
 def chunk_tick_ref(chunk_version, chunk_sync, chunk_dirty,
                    miss, write_acts, arts, write_chunks, *,
                    artifact_tokens: int, chunk_tokens: int,
-                   signal_tokens: int = 12, block_sims: int = 128,
-                   interpret: bool | None = None):
+                   signal_tokens: int = 12):
     """Pure-numpy reference of :func:`chunk_tick_pallas` (serialized
-    agents, same signature/returns) - the scan-style oracle the kernel
-    is asserted bit-identical against, and the route
-    ``REPRO_CHUNK_DIFF=scan`` forces in the service layer."""
+    agents, same inputs and returns) - the scan-style oracle the kernel
+    is asserted bit-identical against."""
     cv = np.array(chunk_version, np.int32)
     cs = np.array(chunk_sync, np.int32)
     dirty = np.array(chunk_dirty, np.int32)

@@ -123,9 +123,7 @@ async def serve_tcp(broker: CoherenceBroker, host: str = "127.0.0.1",
     # a write request carries artifact_tokens JSON ints on one line;
     # asyncio's default 64 KiB readline limit would drop the connection
     # instead of answering, so size the limit to the artifact slot.
-    tokens = getattr(broker.config, "artifact_tokens", None)
-    if tokens is None:          # layered config (sharded plane)
-        tokens = broker.config.core.artifact_tokens
+    tokens = broker.config.core.artifact_tokens
     limit = max(1 << 16, tokens * 16 + (1 << 12))
     return await asyncio.start_server(
         lambda r, w: handle_connection(broker, r, w), host, port,

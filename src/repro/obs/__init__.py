@@ -16,7 +16,7 @@ metrics for the live coherence service:
     listener that charges trace / lower / compile time to the open
     record, its build-event log, and the sweep-call records;
   * :mod:`repro.obs.stats` - the unified ``stats()`` schema both
-    broker flavors serve (with the legacy flat-key deprecation shim);
+    broker flavors serve;
   * :mod:`repro.obs.conformance` - the ``MetricsConformance`` oracle
     leg: every replayable counter recomputed from the captured
     ``ServiceTrace`` and asserted bit-identical to the live registry.
@@ -36,7 +36,7 @@ from repro.obs.runtime import (compile_count, compile_events,
                                reset_compile_log, sweep_records)
 from repro.obs.spans import (BatchRecord, Record, Span, SpanRecorder,
                              span)
-from repro.obs.stats import LEGACY_KEYS, StatsView, unified_stats
+from repro.obs.stats import unified_stats
 from repro.obs.telemetry import BatchObservation, Telemetry
 
 __all__ = [
@@ -47,13 +47,11 @@ __all__ = [
     "Counter",
     "Gauge",
     "Histogram",
-    "LEGACY_KEYS",
     "MetricsConformanceError",
     "MetricsRegistry",
     "Record",
     "Span",
     "SpanRecorder",
-    "StatsView",
     "Telemetry",
     "check_metrics_conformance",
     "compile_count",

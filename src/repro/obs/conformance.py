@@ -184,10 +184,7 @@ def check_metrics_conformance(broker, name: str = "metrics") -> dict:
         raise ValueError(
             "broker runs with telemetry disabled; metrics conformance "
             "needs the live registry (telemetry=True)")
-    capture = (broker.config.service.capture_trace
-               if getattr(broker, "is_sharded", False)
-               else broker.config.capture_trace)
-    if not capture:
+    if not broker.config.service.capture_trace:
         raise ValueError(
             "broker was started with capture_trace=False; metrics "
             "conformance replays the captured ServiceTrace")

@@ -1,14 +1,8 @@
 """One ``stats()`` schema for every broker flavor.
 
-``CoherenceBroker.stats()`` and ``ShardedCoherenceBroker.stats()`` had
-drifted into two ad-hoc flat dicts (the sharded one omitted latency
-percentiles, the plain one omitted capacity metrics).  Both now
+``CoherenceBroker.stats()`` and ``ShardedCoherenceBroker.stats()`` both
 delegate here: :func:`unified_stats` builds one **nested canonical
-schema** (identical key set for both flavors, superset of everything
-either reported) and attaches the old flat key names as a deprecation
-shim - reading a legacy key still works everywhere it used to, but
-warns once per process per key.  Serialization keeps the flat keys
-(TCP ``stats`` consumers parse them), so the shim is wire-compatible.
+schema**, with the same key set for both flavors.
 
 Canonical schema (``schema_version`` 1)::
 
@@ -30,46 +24,7 @@ Canonical schema (``schema_version`` 1)::
 
 from __future__ import annotations
 
-import warnings
-
 import numpy as np
-
-#: flat keys kept as the deprecation shim (the union of the two
-#: pre-unification stats() dicts).
-LEGACY_KEYS = frozenset({
-    "n_actions", "n_batches", "mean_batch",
-    "total_tokens", "fetch_tokens", "signal_tokens", "push_tokens",
-    "n_fetches", "n_hits", "cache_hit_rate",
-    "p50_ms", "p99_ms", "decide_busy_s",
-    "n_shards", "n_hosts", "shard_artifacts",
-    "decide_busy_max_s", "decisions_per_s",
-    "l1_fills", "l2_fills", "l1_bytes", "l2_bytes", "l1_fill_rate",
-    "delta_bytes", "full_bytes", "n_chunks_fetched",
-    "bytes_savings_vs_full", "unique_chunks",
-})
-
-_warned: set = set()
-
-
-class StatsView(dict):
-    """The stats mapping: canonical nested keys plus legacy flat
-    aliases that warn (once per process per key) on access."""
-
-    def __getitem__(self, key):
-        if key in LEGACY_KEYS and key not in _warned:
-            _warned.add(key)
-            warnings.warn(
-                f"stats()[{key!r}] is a deprecated flat alias; read "
-                f"the nested schema (see repro.obs.stats docstring / "
-                f"docs/observability.md)",
-                DeprecationWarning, stacklevel=2)
-        return super().__getitem__(key)
-
-    def get(self, key, default=None):
-        try:
-            return self[key]
-        except KeyError:
-            return default
 
 
 def _percentiles(latencies) -> dict:
@@ -103,15 +58,14 @@ def _mesi_section(tel) -> dict:
     }
 
 
-def unified_stats(broker) -> StatsView:
-    """Build the canonical nested stats mapping (+ legacy flat aliases)
-    for a plain or sharded broker."""
+def unified_stats(broker) -> dict:
+    """Build the canonical nested stats mapping for a plain or sharded
+    broker."""
     sharded = getattr(broker, "is_sharded", False)
     led = broker.ledger
     tel = getattr(broker, "telemetry", None)
 
     if sharded:
-        strategy = broker.config.core.strategy
         backend = broker.brokers[0].decider.backend
         n_shards = broker.n_shards
         n_hosts = broker.config.topology.n_hosts
@@ -123,7 +77,6 @@ def unified_stats(broker) -> StatsView:
                              for b in broker.brokers)
                          if chunked else 0)
     else:
-        strategy = broker.config.strategy
         backend = broker.decider.backend
         n_shards, n_hosts = 1, 1
         shard_artifacts = [len(broker.names)]
@@ -134,9 +87,9 @@ def unified_stats(broker) -> StatsView:
                          if chunked else 0)
 
     n_actions = led.n_reads + led.n_writes
-    out = StatsView({
+    out = {
         "schema_version": 1,
-        "strategy": strategy,
+        "strategy": broker.config.core.strategy,
         "backend": backend,
         "topology": {"n_shards": n_shards, "n_hosts": n_hosts,
                      "shard_artifacts": shard_artifacts},
@@ -167,7 +120,7 @@ def unified_stats(broker) -> StatsView:
             "spans_recorded": (tel.spans.n_recorded if tel else 0),
             "compile_traces": 0,
         },
-    })
+    }
     if tel is not None:
         from repro.obs import runtime
         out["telemetry"]["compile_traces"] = runtime.compile_count()
@@ -185,28 +138,4 @@ def unified_stats(broker) -> StatsView:
         l1["l1_invalidations"] = sum(h.n_invalidations
                                      for h in broker.l1)
         out["l1"] = l1
-
-    # ---- legacy flat aliases (deprecation shim; warn on access)
-    flat = {}
-    flat.update({k: out["decision"][k] for k in (
-        "n_actions", "n_batches", "mean_batch", "decide_busy_s")})
-    flat.update({k: out["ledger"][k] for k in (
-        "total_tokens", "fetch_tokens", "signal_tokens", "push_tokens",
-        "n_fetches", "n_hits", "cache_hit_rate")})
-    flat.update({k: out["latency"][k] for k in ("p50_ms", "p99_ms")})
-    if chunked:
-        flat.update({k: out["wire"][k] for k in (
-            "delta_bytes", "full_bytes", "n_chunks_fetched",
-            "bytes_savings_vs_full", "unique_chunks")})
-    if sharded:
-        flat.update({
-            "n_shards": n_shards, "n_hosts": n_hosts,
-            "shard_artifacts": tuple(shard_artifacts),
-            "decide_busy_max_s": out["decision"]["decide_busy_max_s"],
-            "decisions_per_s": out["decision"]["decisions_per_s"],
-        })
-        flat.update({k: out["l1"][k] for k in (
-            "l1_fills", "l2_fills", "l1_bytes", "l2_bytes",
-            "l1_fill_rate")})
-    dict.update(out, flat)
     return out

@@ -11,8 +11,6 @@ Public surface (stable import paths for examples and docs):
     with micro-batched coherence decisions;
   * :class:`ShardedCoherenceBroker` / :class:`HostL1Directory` - the
     K-shard authority plane with per-host L1 directories;
-  * :class:`BrokerConfig` - legacy flat config, now a thin frozen view
-    over ``CoherenceConfig`` (direct construction warns once);
   * :class:`CoherentClient` / :func:`make_clients` /
     :class:`ServicePortal` / :class:`SyncCoherentClient` - per-agent
     clients (async-native, plus a sync bridge for frameworks);
@@ -26,11 +24,10 @@ Public surface (stable import paths for examples and docs):
     generator over workload-zoo rate matrices.
 """
 
-from repro.configs.coherence import (CoherenceConfig, CoherenceCore,
-                                     ServiceLayer, ShardTopology,
-                                     shard_of_artifact)
-from repro.service.broker import (BROKER_STRATEGIES, BrokerConfig,
-                                  CoherenceBroker, InvariantViolation,
+from repro.configs.coherence import (BROKER_STRATEGIES, CoherenceConfig,
+                                     CoherenceCore, ServiceLayer,
+                                     ShardTopology, shard_of_artifact)
+from repro.service.broker import (CoherenceBroker, InvariantViolation,
                                   ReadResult, WriteResult)
 from repro.service.batching import (BatchDecider, BatchDecision,
                                     resolve_decide_backend)
@@ -52,7 +49,7 @@ __all__ = [
     "connect", "resolve_broker",
     "CoherenceConfig", "CoherenceCore", "ServiceLayer", "ShardTopology",
     "shard_of_artifact",
-    "BROKER_STRATEGIES", "BrokerConfig", "CoherenceBroker",
+    "BROKER_STRATEGIES", "CoherenceBroker",
     "InvariantViolation", "ReadResult", "WriteResult",
     "BatchDecider", "BatchDecision", "resolve_decide_backend",
     "HostL1Directory", "L1Entry", "ShardedCoherenceBroker",

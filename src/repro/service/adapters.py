@@ -102,7 +102,7 @@ class CoherentTool:
 
     def __init__(self, client: AnyClient) -> None:
         self.client = client
-        self._tokens = client_broker(client).config.artifact_tokens
+        self._tokens = client_broker(client).config.core.artifact_tokens
 
     @property
     def spec(self) -> dict:

@@ -27,7 +27,7 @@ simulator (and therefore with the four-way differential oracle):
 
 ``auto`` resolves to the kernel route on a real TPU backend (where the
 sim engine also routes ticks through the kernel) and to ``scan``
-elsewhere; ``REPRO_SERVICE_DECIDE`` forces either.
+elsewhere; a caller forces either with ``backend=``.
 
 A decision is two halves on either route: ``dispatch`` stages the batch
 and calls the device program, returning its outputs unread, and
@@ -39,7 +39,6 @@ before it resolves any, so the shards' programs run at once.
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple
 
 import jax
@@ -48,8 +47,7 @@ import numpy as np
 
 from repro.core import acs
 from repro.kernels.backend import interpret_default, resolve_interpret
-from repro.kernels.chunk_diff import (chunk_tick_pallas, chunk_tick_ref,
-                                      resolve_chunk_route)
+from repro.kernels.chunk_diff import chunk_tick_pallas
 from repro.kernels.mesi_transition import (mesi_decision_dispatch,
                                           mesi_decision_resolve)
 from repro.obs.spans import span
@@ -94,10 +92,9 @@ def _kernel_supported(cfg: acs.ACSConfig) -> bool:
 def resolve_decide_backend(cfg: acs.ACSConfig,
                            backend: str = "auto") -> str:
     """'scan' | 'pallas' for a broker with static config ``cfg``."""
-    forced = os.environ.get("REPRO_SERVICE_DECIDE", backend)
-    if forced == "scan":
+    if backend == "scan":
         return "scan"
-    if forced == "pallas":
+    if backend == "pallas":
         if not _kernel_supported(cfg):
             raise ValueError(
                 "pallas decision route covers lazy/eager/access_count "
@@ -105,8 +102,8 @@ def resolve_decide_backend(cfg: acs.ACSConfig,
                 f"strategy={acs.STRATEGY_NAMES[cfg.strategy]} "
                 f"max_stale_steps={cfg.max_stale_steps}")
         return "pallas"
-    if forced != "auto":
-        raise ValueError(f"unknown decision backend {forced!r}")
+    if backend != "auto":
+        raise ValueError(f"unknown decision backend {backend!r}")
     return ("pallas" if not interpret_default() and _kernel_supported(cfg)
             else "scan")
 
@@ -144,13 +141,6 @@ def _chunk_decider(chunk_version, chunk_sync, chunk_dirty, miss,
         artifact_tokens=artifact_tokens, chunk_tokens=chunk_tokens,
         signal_tokens=signal_tokens, interpret=interpret)
     return tuple(o[0] for o in out)
-
-
-def _chunk_decider_ref(*args, **static):
-    """``chunk_tick_ref`` in ``_chunk_decider``'s place
-    (``REPRO_CHUNK_DIFF=scan``)."""
-    return tuple(o[0] for o in chunk_tick_ref(*(x[None] for x in args),
-                                              **static))
 
 
 #: ACSMetrics counter fields forwarded into the broker's token ledger.
@@ -323,12 +313,8 @@ class BatchDecider:
         if acs.content_enabled(self.cfg):
             # Content plane rides the same serialization order: the
             # chunk tick consumes the per-request miss bits and the
-            # measured dirty masks.  REPRO_CHUNK_DIFF=scan forces the
-            # pure-jnp reference (bit-identical; oracle-checked).
+            # measured dirty masks.
             with span("broker.decide.stage"):
-                tick = (_chunk_decider_ref
-                        if resolve_chunk_route("pallas") == "scan"
-                        else _chunk_decider)
                 wact = (acts_np & writes_np).astype(np.int32)
                 chunk_args = (
                     self.arrays.chunk_version, self.arrays.chunk_sync,
@@ -336,7 +322,7 @@ class BatchDecider:
                     wact, np.asarray(arts, np.int32),
                     np.asarray(write_chunks, np.int32))
             with span("broker.decide.call"):
-                cv, cs, dirty, fetched_c, ccnt = tick(
+                cv, cs, dirty, fetched_c, ccnt = _chunk_decider(
                     *chunk_args,
                     artifact_tokens=self.cfg.artifact_tokens,
                     chunk_tokens=self.cfg.chunk_tokens,

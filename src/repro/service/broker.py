@@ -27,14 +27,13 @@ from __future__ import annotations
 
 import asyncio
 import collections
-import contextvars
 import dataclasses
 import time
-import warnings
 from typing import Callable, Dict, NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from repro.configs.coherence import CoherenceConfig
 from repro.content.chunks import (BYTES_PER_TOKEN, ChunkStore,
                                   diff_chunks)
 from repro.core import acs, invariants
@@ -49,156 +48,8 @@ from repro.service.trace import ServiceTrace
 
 _E = int(MESIState.E)
 
-#: strategies the broker serves.  Broadcast is the *baseline* the bench
-#: compares against analytically; TTL epochs are defined in terms of the
-#: simulator's logical step clock, which a live service does not have.
-BROKER_STRATEGIES = ("lazy", "eager", "access_count")
-
-
 class InvariantViolation(AssertionError):
     """A verified CCS invariant failed on live broker state."""
-
-
-#: set while ``CoherenceConfig.broker_view()`` constructs the flat view,
-#: so only *direct* legacy construction triggers the deprecation shim.
-_VIEW_CONSTRUCTION = contextvars.ContextVar("broker_view_construction",
-                                            default=False)
-_LEGACY_WARNED = False
-
-
-def _warn_legacy_broker_config() -> None:
-    global _LEGACY_WARNED
-    if _LEGACY_WARNED:
-        return
-    _LEGACY_WARNED = True
-    warnings.warn(
-        "constructing BrokerConfig directly is deprecated: it is now a "
-        "thin frozen view over the layered "
-        "repro.configs.CoherenceConfig (core -> service -> shard "
-        "topology); build one with CoherenceConfig.make(...) and "
-        "connect()/broker_view().  Direct construction keeps working "
-        "(ledgers are byte-identical) but loses the topology layer.",
-        DeprecationWarning, stacklevel=3)
-
-
-@dataclasses.dataclass(frozen=True)
-class BrokerConfig:
-    """Static single-authority service parameters (baked into the
-    compiled decider).
-
-    Since the layered-config redesign this is a *thin frozen view* over
-    ``repro.configs.CoherenceConfig``'s core + service layers - the
-    blessed constructors are ``CoherenceConfig.broker_view()`` and
-    ``repro.service.connect(...)``.  Direct construction is a
-    deprecation shim: it warns once per process and keeps working
-    byte-identically."""
-
-    n_agents: int
-    artifacts: tuple
-    artifact_tokens: int = 4096
-    strategy: str = "lazy"
-    access_k: int = 8
-    max_stale_steps: int = 0       # 0 disables K-staleness enforcement
-    batch_window: float = 0.0      # extra coalescing wait (s); 0 = one
-                                   # event-loop pass
-    max_batch: int = 0             # 0 = up to n_agents requests
-    backend: str = "auto"          # decision route: auto | scan | pallas
-    check_invariants: bool = True
-    #: audit-trace capture.  The trace grows one StepRecord per batch,
-    #: so indefinitely-running deployments (the TCP frontend) disable
-    #: it; bounded load runs keep it on for oracle replay.
-    capture_trace: bool = True
-    #: ring-buffer size for per-decision latency samples (stats
-    #: percentiles); bounds the broker's memory under open-ended load.
-    latency_window: int = 1 << 20
-    #: chunk-granular content plane (``repro.content``): with
-    #: ``chunk_tokens > 0`` the broker content-addresses every
-    #: artifact's chunks, a write's dirty set is *measured* by digest
-    #: diff, and a read miss ships only the reader's stale chunks
-    #: (``ReadResult.delta``).  0 = whole-artifact payloads.
-    chunk_tokens: int = 0
-    #: telemetry plane (``repro.obs``): MESI perf counters, span
-    #: tracing and the metrics-conformance oracle leg.  Off = the
-    #: broker keeps only the ledger/trace it always kept.
-    telemetry: bool = True
-
-    def __post_init__(self):
-        if not _VIEW_CONSTRUCTION.get():
-            _warn_legacy_broker_config()
-        if self.strategy not in BROKER_STRATEGIES:
-            raise ValueError(
-                f"broker serves {BROKER_STRATEGIES}, got "
-                f"{self.strategy!r} (broadcast is the baseline, not a "
-                f"servable strategy; ttl is simulation-clock-only)")
-        if len(set(self.artifacts)) != len(self.artifacts):
-            raise ValueError("duplicate artifact ids")
-        if self.chunk_tokens > 0:
-            if acs.STRATEGY_CODES[
-                    self.strategy] not in acs.CONTENT_STRATEGIES:
-                raise ValueError(
-                    f"chunked broker serves "
-                    f"{[acs.STRATEGY_NAMES[s] for s in acs.CONTENT_STRATEGIES]}"
-                    f" (delta fetch is pull-only); got "
-                    f"{self.strategy!r}")
-            if self.max_stale_steps > 0:
-                # the byte-exact oracle leg (verify_broker_content)
-                # covers max_stale_steps=0 only; allowing the combo
-                # would build a broker that can never be verified
-                raise ValueError(
-                    "chunked broker does not support K-staleness "
-                    "enforcement (max_stale_steps > 0): the byte-exact "
-                    "content oracle covers the pull-only invalidation "
-                    "protocol without revalidation; run either "
-                    "chunk_tokens=0 or max_stale_steps=0")
-
-    def acs_config(self, n_steps: int = 1) -> acs.ACSConfig:
-        return acs.ACSConfig(
-            n_agents=self.n_agents, n_artifacts=len(self.artifacts),
-            artifact_tokens=self.artifact_tokens, n_steps=n_steps,
-            strategy=acs.STRATEGY_CODES[self.strategy],
-            access_k=self.access_k,
-            max_stale_steps=self.max_stale_steps,
-            chunk_tokens=self.chunk_tokens)
-
-    @classmethod
-    def _from_layers(cls, coherence) -> "BrokerConfig":
-        """The blessed view constructor (``CoherenceConfig.broker_view``
-        calls this); suppresses the legacy-construction warning."""
-        token = _VIEW_CONSTRUCTION.set(True)
-        try:
-            return cls(
-                n_agents=coherence.n_agents,
-                artifacts=tuple(coherence.artifacts),
-                artifact_tokens=coherence.core.artifact_tokens,
-                strategy=coherence.core.strategy,
-                access_k=coherence.core.access_k,
-                max_stale_steps=coherence.core.max_stale_steps,
-                batch_window=coherence.service.batch_window,
-                max_batch=coherence.service.max_batch,
-                backend=coherence.service.backend,
-                check_invariants=coherence.service.check_invariants,
-                capture_trace=coherence.service.capture_trace,
-                latency_window=coherence.service.latency_window,
-                chunk_tokens=coherence.core.chunk_tokens,
-                telemetry=coherence.service.telemetry)
-        finally:
-            _VIEW_CONSTRUCTION.reset(token)
-
-    def coherence_config(self):
-        """Lift this flat view back into the layered config (trivial
-        topology)."""
-        from repro.configs.coherence import from_broker_fields
-        return from_broker_fields(
-            self.n_agents, self.artifacts,
-            artifact_tokens=self.artifact_tokens, strategy=self.strategy,
-            access_k=self.access_k, max_stale_steps=self.max_stale_steps,
-            batch_window=self.batch_window, max_batch=self.max_batch,
-            backend=self.backend,
-            check_invariants=self.check_invariants,
-            capture_trace=self.capture_trace,
-            latency_window=self.latency_window,
-            chunk_tokens=self.chunk_tokens,
-            telemetry=self.telemetry)
 
 
 class ReadResult(NamedTuple):
@@ -275,25 +126,24 @@ class CoherenceBroker:
             await broker.read(agent=0, artifact="plan")
     """
 
-    def __init__(self, config: BrokerConfig,
+    def __init__(self, config: CoherenceConfig,
                  contents: Optional[Dict[str, Sequence[int]]] = None,
                  *, on_commit: Optional[Callable] = None,
                  device=None, telemetry: Optional[Telemetry] = None,
                  shard: int = 0,
                  wake: Optional[asyncio.Event] = None) -> None:
-        if hasattr(config, "broker_view"):   # layered CoherenceConfig
-            if not config.topology.trivial:
-                raise ValueError(
-                    "CoherenceBroker is the single-authority shard; "
-                    "non-trivial topologies need "
-                    "repro.service.connect(...) / "
-                    "ShardedCoherenceBroker")
-            config = config.broker_view()
+        if not config.topology.trivial:
+            raise ValueError(
+                "CoherenceBroker is the single-authority shard; "
+                "non-trivial topologies need "
+                "repro.service.connect(...) / "
+                "ShardedCoherenceBroker")
         self.config = config
+        core, service = config.core, config.service
         self.names = tuple(config.artifacts)
         self._index = {a: d for d, a in enumerate(self.names)}
         self.acs_config = config.acs_config()
-        self.decider = BatchDecider(self.acs_config, config.backend,
+        self.decider = BatchDecider(self.acs_config, service.backend,
                                     device=device)
         #: called as ``on_commit(broker, commit)`` after every committed
         #: micro-batch (the sharded authority plane uses this to build
@@ -311,25 +161,25 @@ class CoherenceBroker:
         #: deployment hands ONE shared ``Telemetry`` to every
         #: sub-broker; a standalone broker builds its own.
         self.telemetry: Optional[Telemetry] = telemetry
-        if self.telemetry is None and config.telemetry:
+        if self.telemetry is None and service.telemetry:
             self.telemetry = Telemetry(
-                config.n_agents, strategy=config.strategy,
+                config.n_agents, strategy=core.strategy,
                 backend=self.decider.backend)
         self.bus = EventBus()
         self.store = ArtifactStore()
         for name in self.names:
             content = (contents or {}).get(
-                name, list(range(config.artifact_tokens)))
-            if len(content) != config.artifact_tokens:
+                name, list(range(core.artifact_tokens)))
+            if len(content) != core.artifact_tokens:
                 raise ValueError(
                     f"artifact {name!r} content length {len(content)} != "
-                    f"artifact_tokens {config.artifact_tokens} (the "
+                    f"artifact_tokens {core.artifact_tokens} (the "
                     f"broker's accounting is fixed-slot, like the "
                     f"simulator's)")
             self.store.put(name, list(content))
         self.chunks: Optional[ChunkStore] = None
-        if config.chunk_tokens > 0:
-            self.chunks = ChunkStore(self.store, config.chunk_tokens)
+        if core.chunk_tokens > 0:
+            self.chunks = ChunkStore(self.store, core.chunk_tokens)
             for name in self.names:
                 self.chunks.register(name)
         #: bytes-on-wire ledger (content plane; all zero when off)
@@ -337,7 +187,7 @@ class CoherenceBroker:
                      "n_chunks_fetched": 0}
         self.ledger = TokenLedger()
         self.trace = ServiceTrace.for_broker(config)
-        self.latencies = collections.deque(maxlen=config.latency_window)
+        self.latencies = collections.deque(maxlen=service.latency_window)
         self.n_batches = 0
         self._pending: list = []
         #: set by every submit.  A sharded plane passes its own
@@ -392,10 +242,10 @@ class CoherenceBroker:
         canonical content (pointer-semantics update)."""
         if content is not None:
             content = tuple(content)
-            if len(content) != self.config.artifact_tokens:
+            if len(content) != self.config.core.artifact_tokens:
                 raise ValueError(
                     f"write of {len(content)} tokens to fixed "
-                    f"{self.config.artifact_tokens}-token artifact slot")
+                    f"{self.config.core.artifact_tokens}-token artifact slot")
         return await self._submit(agent, artifact, True, content)
 
     def _submit(self, agent: int, artifact: str, is_write: bool,
@@ -424,8 +274,8 @@ class CoherenceBroker:
             self._wake.clear()
             if self._closed and not self._pending:
                 return
-            if self.config.batch_window > 0:
-                await asyncio.sleep(self.config.batch_window)
+            if self.config.service.batch_window > 0:
+                await asyncio.sleep(self.config.service.batch_window)
             else:
                 # one event-loop pass: every already-scheduled client
                 # coroutine gets to enqueue before the batch is cut.
@@ -441,7 +291,7 @@ class CoherenceBroker:
         """Drain pending FIFO into a micro-batch: at most one request
         per agent (a batch is one serialized authority pass; a second
         request from the same agent belongs to the next pass)."""
-        max_batch = self.config.max_batch or self.config.n_agents
+        max_batch = self.config.service.max_batch or self.config.n_agents
         batch, rest, seen = [], [], set()
         for req in self._pending:
             if req.agent in seen or len(batch) >= max_batch:
@@ -543,7 +393,7 @@ class CoherenceBroker:
             new = (list(req.content) if req.content is not None
                    else cur)
             masks[req.agent] = diff_chunks(cur, new,
-                                           self.config.chunk_tokens)
+                                           self.config.core.chunk_tokens)
             pending[name] = new
         return masks
 
@@ -579,7 +429,7 @@ class CoherenceBroker:
 
         with span("broker.checks"):
             ver_after = np.asarray(self.decider.arrays.version, np.int64)
-            if self.config.check_invariants:
+            if self.config.service.check_invariants:
                 self._check_invariants(batch, ver_before, ver_after)
 
         with span("broker.respond"):
@@ -638,7 +488,7 @@ class CoherenceBroker:
             self.n_batches += 1
 
         with span("broker.telemetry"):
-            if self.config.capture_trace:
+            if self.config.service.capture_trace:
                 self.trace.append_step(acts, arts, writes, decision.miss,
                                        decision.version, latencies,
                                        write_chunks=wmasks,
@@ -690,13 +540,13 @@ class CoherenceBroker:
             raise InvariantViolation(
                 f"version bump mismatch: delta {ver_after - ver_before}"
                 f" vs writes {bumps}")
-        if self.config.max_stale_steps > 0:
+        if self.config.core.max_stale_steps > 0:
             consumed = int(self.decider.metrics.max_consumed_staleness)
-            if consumed > self.config.max_stale_steps:
+            if consumed > self.config.core.max_stale_steps:
                 raise InvariantViolation(
                     f"K-staleness violated: served a hit "
                     f"{consumed} action-steps stale "
-                    f"(K={self.config.max_stale_steps})")
+                    f"(K={self.config.core.max_stale_steps})")
 
     # ----------------------------------------------------------- stats
     @property
@@ -709,7 +559,6 @@ class CoherenceBroker:
         return np.asarray(self.decider.arrays.version, np.int32)
 
     def stats(self) -> dict:
-        """The unified stats mapping (``repro.obs.stats``): canonical
-        nested schema plus the legacy flat aliases as a deprecation
-        shim."""
+        """The unified stats mapping (``repro.obs.stats``): the
+        canonical nested schema."""
         return unified_stats(self)

@@ -14,20 +14,13 @@ import asyncio
 import threading
 from typing import Optional, Sequence
 
+from repro.configs.coherence import CoherenceConfig
 from repro.content.chunks import apply_delta
-from repro.service.broker import (BrokerConfig, CoherenceBroker,
-                                  ReadResult, WriteResult)
+from repro.service.broker import CoherenceBroker, ReadResult, WriteResult
 
 
 class DeltaMismatch(AssertionError):
     """A delta-patched mirror diverged from the authority copy."""
-
-
-def _chunk_tokens(config) -> int:
-    """Chunk granularity of a flat BrokerConfig or a layered
-    CoherenceConfig (clients serve both broker flavors)."""
-    core = getattr(config, "core", None)
-    return core.chunk_tokens if core is not None else config.chunk_tokens
 
 
 class CoherentClient:
@@ -56,7 +49,7 @@ class CoherentClient:
     def _patch_mirror(self, artifact: str, res: ReadResult) -> None:
         if res.delta is None:
             return
-        ct = _chunk_tokens(self.broker.config)
+        ct = self.broker.config.core.chunk_tokens
         base = self._mirror.get(artifact)
         if base is None:
             # first contact: adopt the full copy (the broker charged a
@@ -118,7 +111,7 @@ class ServicePortal:
 
     _CALL_TIMEOUT_S = 60.0
 
-    def __init__(self, config: BrokerConfig,
+    def __init__(self, config: CoherenceConfig,
                  contents: Optional[dict] = None) -> None:
         self._loop = asyncio.new_event_loop()
         self._thread = threading.Thread(
@@ -129,8 +122,8 @@ class ServicePortal:
 
     @staticmethod
     async def _make_broker(config, contents):
-        # topology-neutral: a layered config with shards/hosts gets the
-        # sharded authority plane, anything else the single broker
+        # topology-neutral: a config with shards/hosts gets the sharded
+        # authority plane, anything else the single broker
         from repro.service.connect import resolve_broker
         return await resolve_broker(config, contents).start()
 

@@ -39,12 +39,9 @@ def resolve_broker(config: CoherenceConfig,
 
     Trivial topology (1 shard, 1 host) -> the plain single-writer
     ``CoherenceBroker`` (byte-identical to the pre-sharding service);
-    anything wider -> ``ShardedCoherenceBroker``.  Legacy flat
-    ``BrokerConfig``s are lifted into the layered config first."""
-    if not hasattr(config, "topology"):      # legacy BrokerConfig
-        config = config.coherence_config()
+    anything wider -> ``ShardedCoherenceBroker``."""
     if config.topology.trivial:
-        return CoherenceBroker(config.broker_view(), contents)
+        return CoherenceBroker(config, contents)
     return ShardedCoherenceBroker(config, contents)
 
 
@@ -55,10 +52,10 @@ def connect(config: Optional[CoherenceConfig] = None, *,
             sync: bool = False, **knobs):
     """Build an authority handle without naming its implementation.
 
-    Either pass a prebuilt ``CoherenceConfig`` (or legacy
-    ``BrokerConfig``), or flat knobs (``n_agents`` + ``artifacts``
-    plus any core / service / topology field, with ``shards`` /
-    ``hosts`` aliases) and the layered config is assembled here.
+    Either pass a prebuilt ``CoherenceConfig``, or flat knobs
+    (``n_agents`` + ``artifacts`` plus any core / service / topology
+    field, with ``shards`` / ``hosts`` aliases) and the layered config
+    is assembled here.
 
     Returns an *unstarted* broker - use ``async with`` (or ``await
     .start()``).  With ``sync=True`` returns a started
@@ -71,12 +68,9 @@ def connect(config: Optional[CoherenceConfig] = None, *,
                 "connect() needs either a config or both n_agents= "
                 "and artifacts=")
         config = CoherenceConfig.make(n_agents, artifacts, **knobs)
-    else:
-        if knobs or n_agents is not None or artifacts is not None:
-            raise TypeError(
-                "pass either a prebuilt config or flat knobs, not both")
-        if not hasattr(config, "topology"):  # legacy BrokerConfig
-            config = config.coherence_config()
+    elif knobs or n_agents is not None or artifacts is not None:
+        raise TypeError(
+            "pass either a prebuilt config or flat knobs, not both")
     if sync:
         from repro.service.client import ServicePortal
         return ServicePortal(config, contents)

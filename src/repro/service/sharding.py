@@ -50,6 +50,7 @@ from typing import Dict, NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from repro.configs.coherence import CoherenceConfig
 from repro.content.chunks import BYTES_PER_TOKEN
 from repro.core.protocol import TokenLedger
 from repro.obs.spans import span
@@ -129,20 +130,9 @@ class ShardedCoherenceBroker:
     #: lets ``trace.verify_broker`` dispatch to the sharded verifier.
     is_sharded = True
 
-    def __init__(self, config,
+    def __init__(self, config: CoherenceConfig,
                  contents: Optional[Dict[str, Sequence[int]]] = None
                  ) -> None:
-        if not hasattr(config, "topology"):
-            raise TypeError(
-                "ShardedCoherenceBroker needs a layered "
-                "repro.configs.CoherenceConfig (BrokerConfig has no "
-                "topology layer); build one with CoherenceConfig.make "
-                "or repro.service.connect(...)")
-        if config.core.max_stale_steps > 0:
-            raise ValueError(
-                "sharded authority does not serve simulator K-staleness"
-                " (per-shard action clocks diverge from the global "
-                "clock); bound L1 staleness with l1_max_version_lag")
         from repro.launch.mesh import shard_devices
 
         self.config = config
@@ -155,7 +145,7 @@ class ShardedCoherenceBroker:
         devices = shard_devices(self.n_shards)
 
         #: the ONE global audit trace, in event-loop commit order
-        self.trace = ServiceTrace.for_broker(config.broker_view())
+        self.trace = ServiceTrace.for_broker(config)
         self.trace.n_shards = self.n_shards
         self.trace.artifact_shards = self.artifact_shards
         self._capture = config.service.capture_trace
@@ -201,7 +191,7 @@ class ShardedCoherenceBroker:
                                 for name in view.artifacts
                                 if name in contents}
             self.brokers.append(CoherenceBroker(
-                view.broker_view(), sub_contents,
+                view, sub_contents,
                 on_commit=functools.partial(self._commit, shard),
                 device=devices[shard],
                 telemetry=self.telemetry, shard=shard, wake=self._wake))
@@ -470,7 +460,6 @@ class ShardedCoherenceBroker:
 
     # ----------------------------------------------------------- stats
     def stats(self) -> dict:
-        """The unified stats mapping (``repro.obs.stats``): canonical
-        nested schema plus the legacy flat aliases as a deprecation
-        shim (identical schema to the plain broker's)."""
+        """The unified stats mapping (``repro.obs.stats``): the
+        canonical nested schema, identical to the plain broker's."""
         return unified_stats(self)

@@ -27,6 +27,11 @@ import numpy as np
 
 from repro.core import acs
 
+#: the serialized trace format :meth:`ServiceTrace.to_json` writes and
+#: :meth:`ServiceTrace.from_json` reads: chunk_tokens and step chunks,
+#: shard topology and step shard, per-step decide_s and batch_size.
+SCHEMA_VERSION = 4
+
 
 @dataclasses.dataclass
 class StepRecord:
@@ -45,18 +50,10 @@ class StepRecord:
     #: broker).  Steps from different shards interleave in *global
     #: commit order* - the one serializable order the oracle replays.
     shard: int = -1
-    #: v4 telemetry stamps: decision-kernel wall time for this batch
-    #: and the cut batch size (requests decided together).  Defaults
-    #: are what v3-and-older traces load with; ``batch_size`` falls
-    #: back to ``len(agents)`` when unstamped (-1), so offline latency
-    #: reconstruction works on any trace vintage.
+    #: telemetry stamps: decision-kernel wall time for this batch and
+    #: the cut batch size (requests decided together)
     decide_s: float = 0.0
     batch_size: int = -1
-
-    @property
-    def size(self) -> int:
-        """Batch size, robust to v3 traces (unstamped -> len(agents))."""
-        return self.batch_size if self.batch_size >= 0 else len(self.agents)
 
 
 @dataclasses.dataclass
@@ -81,13 +78,16 @@ class ServiceTrace:
 
     @classmethod
     def for_broker(cls, config) -> "ServiceTrace":
+        """An empty trace for a broker serving ``config`` (a
+        ``repro.configs.CoherenceConfig``)."""
+        core = config.core
         return cls(n_agents=config.n_agents,
                    n_artifacts=len(config.artifacts),
-                   artifact_tokens=config.artifact_tokens,
-                   strategy=config.strategy,
-                   access_k=config.access_k,
-                   max_stale_steps=config.max_stale_steps,
-                   chunk_tokens=getattr(config, "chunk_tokens", 0))
+                   artifact_tokens=core.artifact_tokens,
+                   strategy=core.strategy,
+                   access_k=core.access_k,
+                   max_stale_steps=core.max_stale_steps,
+                   chunk_tokens=core.chunk_tokens)
 
     # -------------------------------------------------------- capture
     def append_step(self, acts, arts, writes, miss, version,
@@ -164,14 +164,13 @@ class ServiceTrace:
     # ----------------------------------------------- offline telemetry
     def latency_report(self) -> dict:
         """Reconstruct the service latency/decide histograms from the
-        trace alone (no live broker needed).  v4 traces carry per-step
-        decision wall time and batch size; v3-and-older traces yield
-        zeros for ``decide_*`` and ``len(agents)`` batch sizes."""
+        trace alone (no live broker needed): every step carries its
+        decision wall time and batch size."""
         lat = np.asarray([x for s in self.steps for x in s.latency_s],
                          float)
         if lat.size == 0:
             lat = np.zeros(1)
-        sizes = [s.size for s in self.steps]
+        sizes = [s.batch_size for s in self.steps]
         decide = [s.decide_s for s in self.steps]
         return {
             "n_steps": self.n_steps,
@@ -187,27 +186,26 @@ class ServiceTrace:
     # --------------------------------------------------- serialization
     def to_json(self) -> str:
         payload = dataclasses.asdict(self)
-        # v2: chunk_tokens + step chunks; v3: shard topology + step
-        # shard; v4: per-step decide_s + batch_size telemetry stamps
-        payload["schema_version"] = 4
+        payload["schema_version"] = SCHEMA_VERSION
         return json.dumps(payload, indent=2)
 
     @classmethod
     def from_json(cls, text: str) -> "ServiceTrace":
+        """Load a trace that :meth:`to_json` wrote (schema version 4;
+        any other version is refused)."""
         payload = json.loads(text)
-        payload.pop("schema_version", None)
-        payload.setdefault("chunk_tokens", 0)   # v1 traces
-        payload.setdefault("n_shards", 1)       # v1/v2 traces
-        payload["artifact_shards"] = tuple(
-            payload.get("artifact_shards", ()))
+        version = payload.pop("schema_version", None)
+        if version != SCHEMA_VERSION:
+            raise ValueError(
+                f"ServiceTrace schema_version {version!r} is not "
+                f"supported; this build reads version {SCHEMA_VERSION}")
+        payload["artifact_shards"] = tuple(payload["artifact_shards"])
 
         def record(s: dict) -> StepRecord:
-            chunks = tuple(tuple(c) for c in s.pop("chunks", ()))
-            shard = int(s.pop("shard", -1))
-            decide_s = float(s.pop("decide_s", 0.0))    # v3 traces
-            batch_size = int(s.pop("batch_size", -1))   # v3 traces
-            return StepRecord(chunks=chunks, shard=shard,
-                              decide_s=decide_s, batch_size=batch_size,
+            chunks = tuple(tuple(c) for c in s.pop("chunks"))
+            return StepRecord(chunks=chunks, shard=int(s.pop("shard")),
+                              decide_s=float(s.pop("decide_s")),
+                              batch_size=int(s.pop("batch_size")),
                               **{k: tuple(v) for k, v in s.items()})
 
         steps = [record(s) for s in payload.pop("steps")]
@@ -243,7 +241,7 @@ def verify_broker(broker, name: str = "service"):
     from repro.sim import oracle
     if getattr(broker, "is_sharded", False):
         return verify_sharded_broker(broker, name=name)
-    if not broker.config.capture_trace:
+    if not broker.config.service.capture_trace:
         raise ValueError(
             "broker was started with capture_trace=False (unbounded "
             "deployments); oracle verification needs the audit trace")
@@ -391,7 +389,7 @@ def _verify_sharded_content(broker, report, name: str):
                     f"chunk index of {artifact!r} does not reassemble "
                     f"to the canonical artifact on its shard")
             if reassemble(split_chunks(
-                    canonical, sub.config.chunk_tokens)) != canonical:
+                    canonical, sub.config.core.chunk_tokens)) != canonical:
                 raise oracle.ConformanceError(
                     f"chunk round-trip broke for {artifact!r}")
     return creport
@@ -436,7 +434,7 @@ def verify_broker_content(broker, name: str = "service"):
                 f"chunk index of {artifact!r} does not reassemble to "
                 f"the canonical artifact")
         if reassemble(split_chunks(canonical,
-                                   broker.config.chunk_tokens)
+                                   broker.config.core.chunk_tokens)
                       ) != canonical:
             raise oracle.ConformanceError(
                 f"chunk round-trip broke for {artifact!r}")

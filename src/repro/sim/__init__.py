@@ -10,8 +10,7 @@ from repro.sim.engine import (
     RunStats, RunResult, Comparison, run_scenario, compare, compare_grid,
     compare_workloads, run_workload, sweep_volatility, sweep_cells,
     trace_count, reset_trace_count, trace_counter, TraceCounter,
-    clear_compile_cache, resolve_tick_backend, resolve_sweep_devices,
-    shard_plan, ShardPlan,
+    clear_compile_cache, resolve_tick_backend, shard_plan, ShardPlan,
 )
 from repro.sim.workloads import (
     Workload, FAMILIES, FAMILY_SEEDS, make, zoo, random_workload,
@@ -28,8 +27,8 @@ __all__ = [
     "compare_grid", "compare_workloads", "run_workload",
     "sweep_volatility", "sweep_cells", "trace_count",
     "reset_trace_count", "trace_counter", "TraceCounter",
-    "clear_compile_cache", "resolve_tick_backend",
-    "resolve_sweep_devices", "shard_plan", "ShardPlan",
+    "clear_compile_cache", "resolve_tick_backend", "shard_plan",
+    "ShardPlan",
     "Workload", "FAMILIES", "FAMILY_SEEDS", "make", "zoo",
     "random_workload", "zipf_weights",
 ]

@@ -26,11 +26,11 @@ make that possible:
      broadcast baseline and the coherent variant along a leading variant
      axis *inside* the same jitted program - one launch, not two.
   4. **Device sharding with a global key schedule.**  When more than
-     one local device is attached (``resolve_sweep_devices``; force
-     with ``REPRO_SWEEP_DEVICES=n`` or the ``devices=`` argument), the
-     grid program is wrapped in ``shard_map`` over a 1-D mesh: the
-     ``runs`` axis is sharded (falling back to the ``workloads`` /
-     scenario-cell axis, else padding runs - ``shard_plan``).  Episode
+     one local device is attached (every local device by default; cap
+     the count with the ``devices=`` argument), the grid program is
+     wrapped in ``shard_map`` over a 1-D mesh: the ``runs`` axis is
+     sharded (falling back to the ``workloads`` / scenario-cell axis,
+     else padding runs - ``shard_plan``).  Episode
      keys are derived *inside* the program by ``acs.run_keys`` -
      ``fold_in`` on the **global** run index carried by a sharded
      ``run_ids`` operand, never on device-local position - so sharded
@@ -43,7 +43,7 @@ Per-tick MESI transitions route through the Pallas kernel
 (``repro.kernels.mesi_transition``) when a real TPU backend is attached
 and the flattened batch is large enough to fill it; otherwise the
 vectorized ``lax.scan`` path (vmapped ``acs.run_episode``) is used.
-Force either with ``REPRO_SIM_TICK=pallas|scan``.  Under ``shard_map``
+Force either with the ``tick_backend=`` argument.  Under ``shard_map``
 the kernel is invoked per device on that device's slice of the episode
 batch.
 
@@ -55,7 +55,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 import warnings
 from typing import NamedTuple, Optional, Sequence
 
@@ -171,11 +170,6 @@ def _pallas_tick_supported(cfg: acs.ACSConfig) -> bool:
 
 def resolve_tick_backend(cfg: acs.ACSConfig, batch: int) -> str:
     """'pallas' | 'scan' for a grid of ``batch`` flattened episodes."""
-    forced = os.environ.get("REPRO_SIM_TICK", "auto")
-    if forced == "scan":
-        return "scan"
-    if forced == "pallas":
-        return "pallas" if _pallas_tick_supported(cfg) else "scan"
     if (not interpret_default() and _pallas_tick_supported(cfg)
             and batch >= PALLAS_MIN_BATCH):
         return "pallas"
@@ -185,27 +179,6 @@ def resolve_tick_backend(cfg: acs.ACSConfig, batch: int) -> str:
 # ---------------------------------------------------------------------------
 # Device sharding.  Sweep grids are embarrassingly parallel along their
 # batch axes; ``shard_plan`` picks which axis a given grid shards over.
-
-
-def resolve_sweep_devices() -> int:
-    """Device count the sweep engine shards over (1 = unsharded).
-
-    ``REPRO_SWEEP_DEVICES=n`` forces a count (capped at the local
-    device count; ``1`` disables sharding); default is every local
-    device.  On a single-device host this is 1 and the engine takes the
-    plain-jit path - byte-for-byte the pre-sharding behavior.
-    """
-    forced = os.environ.get("REPRO_SWEEP_DEVICES", "auto")
-    n_local = jax.local_device_count()
-    if forced != "auto":
-        try:
-            n = int(forced)
-        except ValueError:
-            raise ValueError(
-                f"REPRO_SWEEP_DEVICES must be an integer or 'auto', "
-                f"got {forced!r}") from None
-        return max(1, min(n, n_local))
-    return n_local
 
 
 class ShardPlan(NamedTuple):
@@ -231,12 +204,12 @@ def shard_plan(n_cells: int, n_runs: int,
     Preference order: shard ``runs`` when it divides the device count,
     else shard the cell (``workloads``) axis when that divides, else
     pad ``runs`` up to the next multiple and shard it (the padded tail
-    is sliced off on the host).  ``devices=None`` resolves via
-    ``resolve_sweep_devices``.
+    is sliced off on the host).  ``devices=None`` plans over every
+    local device; on a single-device host that is the plain-jit path.
     """
-    if devices is None:
-        devices = resolve_sweep_devices()
-    devices = max(1, min(devices, jax.local_device_count()))
+    n_local = jax.local_device_count()
+    devices = (n_local if devices is None
+               else max(1, min(devices, n_local)))
     if devices <= 1:
         return ShardPlan(1, None, n_runs)
     if n_runs % devices == 0:

@@ -363,11 +363,10 @@ class TestEngineContentGrid:
 
 
 def _broker_config(backend="auto", chunk_tokens=24):
-    from repro.service import BrokerConfig
-    return BrokerConfig(
-        n_agents=4, artifacts=("plan", "notes", "scratch"),
-        artifact_tokens=96, strategy="lazy", backend=backend,
-        chunk_tokens=chunk_tokens)
+    from repro.service import CoherenceConfig
+    return CoherenceConfig.make(
+        4, ("plan", "notes", "scratch"), artifact_tokens=96,
+        strategy="lazy", backend=backend, chunk_tokens=chunk_tokens)
 
 
 async def _scripted_session(cfg):
@@ -478,23 +477,6 @@ class TestChunkedBroker:
         ot = back.to_oracle_trace()
         assert ot.write_chunks is not None
         assert ot.write_chunks.any()
-
-    def test_rejects_eager_chunked_config(self):
-        from repro.service import BrokerConfig
-        with pytest.raises(ValueError, match="chunked broker"):
-            BrokerConfig(n_agents=2, artifacts=("a",),
-                         artifact_tokens=96, strategy="eager",
-                         chunk_tokens=24)
-
-    def test_rejects_chunked_k_staleness_config(self):
-        # a chunked broker with K-staleness on could never be
-        # oracle-verified (the content harness covers K=0 only), so it
-        # must be unconstructible rather than silently unverifiable
-        from repro.service import BrokerConfig
-        with pytest.raises(ValueError, match="K-staleness"):
-            BrokerConfig(n_agents=2, artifacts=("a",),
-                         artifact_tokens=96, strategy="lazy",
-                         chunk_tokens=24, max_stale_steps=2)
 
 
 # ---------------------------------------------------------------------------

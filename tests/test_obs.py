@@ -20,9 +20,9 @@ tests/README.md "Observability tier"):
     batch that paid it, and which reach a profiler trace nested in the
     benchmark's annotations; the record ring keeps every request of
     the batches it holds;
-  * the unified stats schema and its deprecation shim, trace schema
-    v4 round-trips (v3 payloads load with defaults), the ``metrics``
-    TCP verb, and the build-event log.
+  * the unified stats schema, trace schema v4 round-trips (any other
+    schema version is refused), the ``metrics`` TCP verb, and the
+    build-event log.
 
 Async tests run via ``asyncio.run`` inside plain pytest functions (no
 pytest-asyncio dependency).
@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import asyncio
 import json
-import warnings
 
 import numpy as np
 import pytest
@@ -40,9 +39,8 @@ import pytest
 from repro.obs import (MetricsConformanceError, MetricsRegistry,
                        Telemetry, check_metrics_conformance)
 from repro.obs import runtime as obs_runtime
-from repro.obs import stats as obs_stats
-from repro.service import (BrokerConfig, CoherenceBroker, CoherenceConfig,
-                           ServiceTrace, connect, drive_workload)
+from repro.service import (CoherenceBroker, CoherenceConfig, ServiceTrace,
+                           connect, drive_workload)
 from repro.sim import workloads
 
 pytestmark = pytest.mark.obs
@@ -54,9 +52,9 @@ def _names(m: int) -> tuple:
     return tuple(f"artifact-{d}" for d in range(m))
 
 
-def _config(n: int = 6, m: int = 4, tokens: int = 64, **kw) -> BrokerConfig:
-    return BrokerConfig(n_agents=n, artifacts=_names(m),
-                        artifact_tokens=tokens, **kw)
+def _config(n: int = 6, m: int = 4, tokens: int = 64,
+            **kw) -> CoherenceConfig:
+    return CoherenceConfig.make(n, _names(m), artifact_tokens=tokens, **kw)
 
 
 def _workload(family: str, n: int = 6, m: int = 4, tokens: int = 64,
@@ -117,7 +115,7 @@ def test_snapshot_and_prometheus_round_trip():
         assert line.startswith("#") or len(line.rsplit(" ", 1)) == 2
 
 
-def _batches(config: BrokerConfig, rounds: int, per_round: int,
+def _batches(config: CoherenceConfig, rounds: int, per_round: int,
              capacity: int = 1 << 14) -> CoherenceBroker:
     """``rounds`` batches of ``per_round`` reads each, on a broker whose
     span records hold ``capacity`` batches."""
@@ -276,7 +274,7 @@ def test_staleness_counter_matches_versions():
 
 
 # ---------------------------------------------------------------------------
-# Unified stats schema + deprecation shim.
+# Unified stats schema.
 
 
 def test_stats_nested_schema():
@@ -299,27 +297,11 @@ def test_stats_nested_schema():
     assert stats["mesi"]["invalidation_events"] >= 1
 
 
-def test_stats_legacy_aliases_warn_once():
-    async def main():
-        async with CoherenceBroker(_config()) as broker:
-            await broker.read(0, "artifact-0")
-            return broker.stats()
-    stats = asyncio.run(main())
-    obs_stats._warned.discard("n_actions")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert stats["n_actions"] == 1      # legacy flat alias
-        assert stats["n_actions"] == 1      # second access: no new warn
-        json.dumps(stats)                   # serialization never warns
-    deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-    assert len(deps) == 1
-
-
 # ---------------------------------------------------------------------------
 # Trace schema v4.
 
 
-def test_trace_v4_round_trip_and_v3_defaults():
+def test_trace_v4_round_trip_and_older_schemas_refused():
     async def main():
         async with CoherenceBroker(_config()) as broker:
             await asyncio.gather(*(
@@ -337,14 +319,12 @@ def test_trace_v4_round_trip_and_v3_defaults():
     rep = back.latency_report()
     assert rep["n_steps"] == 2 and rep["max_batch"] == 6
     assert rep["decide_s_total"] > 0.0
-    # a v3 payload (no per-step decide fields) loads with defaults
+    # a trace of any other schema version is outside input: refused
     for step in payload["steps"]:
         del step["decide_s"], step["batch_size"]
     payload["schema_version"] = 3
-    v3 = ServiceTrace.from_json(json.dumps(payload))
-    assert v3.steps[0].decide_s == 0.0
-    assert v3.steps[0].batch_size == -1
-    assert v3.steps[0].size == 6            # falls back to len(agents)
+    with pytest.raises(ValueError, match="schema_version 3"):
+        ServiceTrace.from_json(json.dumps(payload))
 
 
 # ---------------------------------------------------------------------------
@@ -469,12 +449,12 @@ def test_build_time_is_charged_to_the_batch_that_paid_it():
 
 @pytest.mark.pallas
 @pytest.mark.parametrize("chunk_tokens", [0, 20], ids=["plain", "content"])
-def test_kernel_route_builds_once(monkeypatch, chunk_tokens):
+def test_kernel_route_builds_once(chunk_tokens):
     """Kernel route (interpret mode here): the first batch of a fresh
     shape builds the decision programs; every later batch, reads and
     writes alike, dispatches them from the jit cache."""
-    monkeypatch.setenv("REPRO_SERVICE_DECIDE", "pallas")
-    config = _config(n=10, m=5, tokens=80, chunk_tokens=chunk_tokens)
+    config = _config(n=10, m=5, tokens=80, chunk_tokens=chunk_tokens,
+                     backend="pallas")
     calls = []
 
     async def main():

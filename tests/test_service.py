@@ -29,13 +29,15 @@ import sys
 import numpy as np
 import pytest
 
+from repro.core import acs
 from repro.core.states import MESIState
-from repro.service import (BrokerConfig, CoherenceBroker, CoherentClient,
+from repro.service import (CoherenceBroker, CoherenceConfig, CoherentClient,
                            CoherentTool, InvariantViolation, ServicePortal,
                            ServiceTrace, autogen_functions, crewai_tool,
                            drive_workload, langgraph_node, verify_broker)
+from repro.service import batching
 from repro.service.batching import resolve_decide_backend
-from repro.sim import workloads
+from repro.sim import engine, workloads
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
@@ -46,9 +48,9 @@ def _names(m: int) -> tuple:
     return tuple(f"artifact-{d}" for d in range(m))
 
 
-def _config(n: int = 8, m: int = 4, tokens: int = 64, **kw) -> BrokerConfig:
-    return BrokerConfig(n_agents=n, artifacts=_names(m),
-                        artifact_tokens=tokens, **kw)
+def _config(n: int = 8, m: int = 4, tokens: int = 64,
+            **kw) -> CoherenceConfig:
+    return CoherenceConfig.make(n, _names(m), artifact_tokens=tokens, **kw)
 
 
 def _workload(family: str, n: int = 8, m: int = 4, tokens: int = 64,
@@ -110,8 +112,22 @@ def test_rejects_bad_requests():
             with pytest.raises(ValueError):
                 await broker.write(0, "artifact-0", content=[1, 2])
     asyncio.run(main())
-    with pytest.raises(ValueError):
-        BrokerConfig(n_agents=2, artifacts=("a",), strategy="broadcast")
+
+
+@pytest.mark.parametrize("artifacts,knobs,match", [
+    (("a",), dict(strategy="broadcast"), "broker serves"),
+    (("a",), dict(strategy="ttl"), "broker serves"),
+    (("a", "a"), {}, "duplicate artifact"),
+    (("a",), dict(strategy="eager", chunk_tokens=24), "chunked broker"),
+    # a chunked broker with K-staleness on could never be oracle-verified
+    # (the content harness covers K=0 only), so it must be
+    # unconstructible rather than silently unverifiable
+    (("a",), dict(chunk_tokens=24, max_stale_steps=2), "K-staleness"),
+], ids=["broadcast", "ttl", "duplicate", "eager-chunked",
+        "chunked-k-staleness"])
+def test_config_rejects_unservable(artifacts, knobs, match):
+    with pytest.raises(ValueError, match=match):
+        CoherenceConfig.make(2, artifacts, artifact_tokens=96, **knobs)
 
 
 # ---------------------------------------------------------------------------
@@ -287,6 +303,29 @@ def test_backend_resolution_guards():
         resolve_decide_backend(cfg, "pallas")
 
 
+@pytest.mark.parametrize("resolve,strategy,arg,tpu,route", [
+    ("decide", acs.LAZY, "auto", True, "pallas"),
+    ("decide", acs.LAZY, "auto", False, "scan"),
+    ("decide", acs.LAZY, "scan", True, "scan"),
+    ("tick", acs.LAZY, engine.PALLAS_MIN_BATCH - 1, True, "scan"),
+    ("tick", acs.LAZY, engine.PALLAS_MIN_BATCH, True, "pallas"),
+    ("tick", acs.BROADCAST, 10_000, True, "scan"),
+], ids=["decide-auto-tpu", "decide-auto-cpu", "decide-scan-tpu",
+        "tick-below-min-batch", "tick-at-min-batch", "tick-broadcast"])
+def test_route_follows_arguments_and_platform(monkeypatch, resolve,
+                                              strategy, arg, tpu, route):
+    """A route is a function of the caller's argument, the strategy, the
+    batch size and the platform, and of nothing else."""
+    for module in (batching, engine):
+        monkeypatch.setattr(module, "interpret_default", lambda: not tpu)
+    cfg = acs.ACSConfig(n_agents=8, n_artifacts=4, artifact_tokens=64,
+                        n_steps=1, strategy=strategy)
+    if resolve == "decide":
+        assert batching.resolve_decide_backend(cfg, arg) == route
+    else:
+        assert engine.resolve_tick_backend(cfg, arg) == route
+
+
 # ---------------------------------------------------------------------------
 # Adapters + portal.
 
@@ -365,7 +404,7 @@ def test_tcp_frontend_smoke():
                            "artifact": "artifact-0"})
             assert r["version"] == 2 and not r["hit"]
             s = await rpc({"op": "stats"})
-            assert s["stats"]["n_actions"] == 3
+            assert s["stats"]["decision"]["n_actions"] == 3
             bad = await rpc({"op": "read", "agent": 0,
                              "artifact": "nope"})
             assert not bad["ok"] and "unknown artifact" in bad["error"]

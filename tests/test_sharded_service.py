@@ -13,9 +13,8 @@ tier"):
   * L1 fill attribution and the explicit L1-invalidation path behave,
     and a stale L1 entry past the version-lag bound raises
     ``InvariantViolation`` (white-box);
-  * ``connect(...)`` resolves topologies to the right implementation;
-  * the layered ``CoherenceConfig`` and the legacy ``BrokerConfig``
-    shim build byte-identical brokers, and the shim warns exactly once.
+  * ``connect(...)`` resolves topologies to the right implementation,
+    and ``CoherenceConfig.make`` routes each knob to its layer.
 """
 
 from __future__ import annotations
@@ -23,18 +22,16 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import json
-import warnings
 
 import numpy as np
 import pytest
 
 from repro.configs import (CoherenceConfig, CoherenceCore, ServiceLayer,
                            ShardTopology, shard_of_artifact)
-from repro.service import (BrokerConfig, CoherenceBroker,
-                           HostL1Directory, InvariantViolation,
-                           ServicePortal, ShardedCoherenceBroker,
-                           connect, resolve_broker, verify_broker)
-from repro.service import broker as broker_mod
+from repro.service import (CoherenceBroker, HostL1Directory,
+                           InvariantViolation, ServicePortal,
+                           ShardedCoherenceBroker, connect, resolve_broker,
+                           verify_broker)
 from repro.service.trace import verify_sharded_broker
 from repro.sim import oracle
 
@@ -337,10 +334,9 @@ def test_connect_sync_portal_roundtrip():
 
 
 def test_adapters_flat_config_reads_over_sharded_broker():
-    # regression: CoherentTool reads broker.config.artifact_tokens,
-    # which on the sharded plane is the layered CoherenceConfig - the
-    # flat core pass-through properties must keep adapter-style reads
-    # topology-neutral (examples/coherent_service_demo.py hit this).
+    # regression: CoherentTool reads the slot size from broker.config,
+    # which is the same CoherenceConfig on either broker flavor
+    # (examples/coherent_service_demo.py hit this).
     from repro.service import CoherentClient, CoherentTool
 
     async def go():
@@ -352,55 +348,12 @@ def test_adapters_flat_config_reads_over_sharded_broker():
             await tool.acall("write", "artifact-1", "v2")
             r = await tool.acall("read", "artifact-1")
             assert r.version == 2
-            cfg = broker.config
-            assert (cfg.artifact_tokens, cfg.strategy, cfg.access_k,
-                    cfg.max_stale_steps, cfg.chunk_tokens) == (
-                16, cfg.core.strategy, cfg.core.access_k,
-                cfg.core.max_stale_steps, cfg.core.chunk_tokens)
+            assert broker.config.core == CoherenceCore(
+                artifact_tokens=16)
+            assert all(b.config.core == broker.config.core
+                       for b in broker.brokers)
 
     asyncio.run(go())
-
-
-def test_connect_accepts_legacy_broker_config():
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy = BrokerConfig(n_agents=2, artifacts=("a",),
-                              artifact_tokens=16)
-    broker = connect(legacy)
-    assert type(broker) is CoherenceBroker
-    assert broker.config.artifact_tokens == 16
-
-
-def test_config_layering_golden_ledger(monkeypatch):
-    """Legacy direct BrokerConfig and the layered CoherenceConfig build
-    byte-identical brokers - and the deprecation shim warns exactly
-    once per process, never through the blessed view path."""
-    monkeypatch.setattr(broker_mod, "_LEGACY_WARNED", False)
-    with pytest.warns(DeprecationWarning, match="thin frozen view"):
-        legacy = BrokerConfig(n_agents=4, artifacts=_names(3),
-                              artifact_tokens=32)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")     # a second warn would raise
-        BrokerConfig(n_agents=4, artifacts=_names(3),
-                     artifact_tokens=32)
-    monkeypatch.setattr(broker_mod, "_LEGACY_WARNED", False)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")     # blessed path never warns
-        layered = _config(n=4, m=3).broker_view()
-    assert layered == legacy               # frozen views compare equal
-    # round-trip: flat -> layered -> flat
-    assert legacy.coherence_config().broker_view() == legacy
-
-    async def run(config):
-        async with CoherenceBroker(config) as broker:
-            for r in range(6):
-                await asyncio.gather(
-                    broker.write(0, "artifact-0"),
-                    broker.read(1, "artifact-0"),
-                    broker.read(2, "artifact-1"))
-            return dataclasses.astuple(broker.ledger)
-
-    assert asyncio.run(run(legacy)) == asyncio.run(run(_config(n=4, m=3)))
 
 
 def test_make_routes_knobs_to_layers():
@@ -417,10 +370,10 @@ def test_make_routes_knobs_to_layers():
 
 
 # ---------------------------------------------------------------------------
-# Trace schema: shard stamping (v3) + back-compat loads.
+# Trace schema: shard stamping round-trips; other versions are refused.
 
 
-def test_trace_shard_roundtrip_and_back_compat():
+def test_trace_shard_roundtrip_and_old_schema_refused():
     async def go():
         cfg = _config(m=6, shards=2)
         async with connect(cfg) as broker:
@@ -435,7 +388,7 @@ def test_trace_shard_roundtrip_and_back_compat():
     assert payload["n_shards"] == 2
     restored = type(trace).from_json(trace.to_json())
     assert restored == trace
-    # a v2 payload (no shard or timing fields) still loads, unsharded
+    # a v2 payload (no shard or timing fields) is refused by version
     for step in payload["steps"]:
         step.pop("shard")
         step.pop("decide_s")
@@ -443,9 +396,8 @@ def test_trace_shard_roundtrip_and_back_compat():
     payload.pop("n_shards")
     payload.pop("artifact_shards")
     payload["schema_version"] = 2
-    old = type(trace).from_json(json.dumps(payload))
-    assert old.n_shards == 1 and old.artifact_shards == ()
-    assert all(s.shard == -1 for s in old.steps)
+    with pytest.raises(ValueError, match="schema_version 2"):
+        type(trace).from_json(json.dumps(payload))
 
 
 # ---------------------------------------------------------------------------

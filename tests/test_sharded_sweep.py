@@ -70,9 +70,11 @@ class TestShardPlan:
         plan = shard_plan(4, 8, devices=10_000)
         assert plan.devices <= N_DEV
 
-    def test_env_override_disables_sharding(self, monkeypatch):
-        monkeypatch.setenv("REPRO_SWEEP_DEVICES", "1")
-        assert engine.resolve_sweep_devices() == 1
+    def test_env_override_disables_sharding(self):
+        """No environment variable steers the plan: ``devices=None``
+        plans over every local device, and a caller disables sharding
+        with ``devices=1``."""
+        assert shard_plan(4, 8, devices=None).devices == N_DEV
 
     @pytest.mark.skipif(N_DEV != 1, reason="axis rules need a fixed "
                         "device count; covered multi-device below")
